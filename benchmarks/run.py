@@ -25,6 +25,7 @@ validated directionally against its claims in EXPERIMENTS.md.
 from __future__ import annotations
 
 import json
+import sys
 import time
 from pathlib import Path
 
@@ -957,6 +958,8 @@ def main(argv=None) -> "int | None":
                          "prompts); stamped into every serving_traffic "
                          "row so figures name their workload")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.steps is not None and args.steps < 1:
         ap.error(f"--steps must be >= 1, got {args.steps}")
     global STEPS, SEED
@@ -992,13 +995,13 @@ def main(argv=None) -> "int | None":
             failed.append(b.__name__)
         print(f"# {b.__name__} done in {time.perf_counter()-t0:.1f}s",
               flush=True)
-    if failed and args.scenarios:
-        # explicitly-requested scenarios must not rot silently (the CI
-        # smoke relies on a nonzero exit); full runs stay best-effort
+    if failed:
+        # a scenario that raised fails the run, whether it was requested
+        # by name or ran as part of the full sweep
+        print(f"# FAILED: {', '.join(failed)}", file=sys.stderr)
         return 1
     return 0
 
 
 if __name__ == "__main__":
-    import sys
     sys.exit(main())

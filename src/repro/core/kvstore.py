@@ -234,16 +234,19 @@ class TieredKVStore:
     produced by ``models.transformer.cache_struct`` (the engine strips
     the period-stack dim).  ``link`` is a ``transfer.SimLink`` (or any
     object with ``floor(nbytes, t0)``) shared with the weight store so KV
-    pays the same simulated link."""
+    pays the same simulated link.  ``target`` is the jax device loads land
+    on (None: the default device)."""
 
     def __init__(self, unit_shapes: List[Dict[str, tuple]],
                  unit_kinds: List[Dict[str, str]], *, b_max: int,
-                 max_len: int, kv_mode: str = "fp32", link=None):
+                 max_len: int, kv_mode: str = "fp32", link=None,
+                 target=None):
         assert kv_mode in ("fp32", "int4"), kv_mode
         self.b_max = b_max
         self.max_len = max_len
         self.kv_mode = kv_mode
         self.link = link
+        self.target = target
         self.kinds: List[Dict[str, str]] = [dict(k) for k in unit_kinds]
         # running total of compute-precision bytes the load-side dequant
         # materialized — bounded by live extents, never the slab
@@ -396,14 +399,15 @@ class TieredKVStore:
     def _put_padded(self, arr: np.ndarray, lb: int, ll: int, seq: bool):
         sl = arr[:lb, :ll] if seq else arr[:lb]
         if sl.shape == arr.shape:
-            return jnp.asarray(arr)
+            return jax.device_put(arr, self.target)
         if seq:
             ll_b = self._bucket_len(ll)
-            rows = jnp.asarray(self._bucketed(arr, lb, ll, ll_b))
-            dev = jnp.zeros(arr.shape, rows.dtype)
+            rows = jax.device_put(self._bucketed(arr, lb, ll, ll_b),
+                                  self.target)
+            dev = jnp.zeros(arr.shape, rows.dtype, device=self.target)
             return dev.at[:lb, :ll_b].set(rows)
-        rows = jnp.asarray(np.ascontiguousarray(sl))
-        dev = jnp.zeros(arr.shape, rows.dtype)
+        rows = jax.device_put(np.ascontiguousarray(sl), self.target)
+        dev = jnp.zeros(arr.shape, rows.dtype, device=self.target)
         return dev.at[tuple(slice(0, s) for s in sl.shape)].set(rows)
 
     def load(self, j: int, live_b: Optional[int] = None,
@@ -429,10 +433,10 @@ class TieredKVStore:
             leaf = self._units[j][name]
             if isinstance(leaf, _QuantLeaf):
                 ll_b = self._bucket_len(ll)
-                packed = jnp.asarray(self._bucketed(leaf.packed,
-                                                    lb, ll, ll_b))
-                scale = jnp.asarray(self._bucketed(leaf.scale,
-                                                   lb, ll, ll_b))
+                packed = jax.device_put(
+                    self._bucketed(leaf.packed, lb, ll, ll_b), self.target)
+                scale = jax.device_put(
+                    self._bucketed(leaf.scale, lb, ll, ll_b), self.target)
                 full = (self.b_max, self.max_len) + m.feat
                 out[name] = _dequant_pad_rows(packed, scale, leaf.group,
                                               full, m.dtype)

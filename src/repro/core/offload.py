@@ -150,9 +150,21 @@ class DiskStore(Store):
 
 @dataclass
 class MemoryBudget:
-    """Tier capacities for autoconfig (bytes)."""
+    """Tier capacities for autoconfig (bytes).  The defaults describe the
+    paper's laptop; ``for_device`` replaces the device capacity with what
+    the backend reports."""
     device: int = 6 * 2**30        # paper laptop: RTX3060 6GB
     host: int = 16 * 2**30         # 16GB DRAM
     disk: int = 1 * 2**40          # 1TB SSD
     device_bw: float = 12e9        # PCIe x8-ish GPU link (B/s)
     disk_bw: float = 3.5e9         # NVMe read bw (B/s)
+
+    @classmethod
+    def for_device(cls, device=None) -> "MemoryBudget":
+        """The default budget with ``device`` capacity taken from the
+        backend's ``memory_stats()["bytes_limit"]`` (first local device
+        unless one is given).  A backend that reports no capacity (the
+        CPU reports none) keeps the default."""
+        dev = device if device is not None else jax.devices()[0]
+        limit = (dev.memory_stats() or {}).get("bytes_limit")
+        return cls(device=int(limit)) if limit else cls()

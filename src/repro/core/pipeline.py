@@ -306,13 +306,18 @@ class PipelineScheduler:
         mutating KV state outside the pipeline (e.g. a serving slot
         restore writes host KV directly) — every preloaded device copy
         would be stale.  Weight preloads are untouched (weights are
-        immutable)."""
-        for t in self._kv_tasks.values():
+        immutable).  A preload that raised re-raises here: only its
+        result is discarded."""
+        tasks = list(self._kv_tasks.values())
+        self._kv_tasks.clear()
+        err = None
+        for t in tasks:
             try:
                 t.wait()
-            except Exception:
-                pass                  # discarded anyway
-        self._kv_tasks.clear()
+            except Exception as e:  # wait out the rest, then re-raise
+                err = err or e
+        if err is not None:
+            raise err
 
     def drain_saves(self):
         """Block (main thread) until every outstanding KV save has landed.

@@ -170,9 +170,13 @@ class TieredWeightStore:
     def __init__(self, *, placement: str, host, device, disk,
                  quant: Optional[str] = None, fused_int4: bool = True,
                  block_bytes: int = DEFAULT_BLOCK, n_io_threads: int = 3,
-                 cold_reads: bool = False, sim_bw: Optional[float] = None):
+                 cold_reads: bool = False, sim_bw: Optional[float] = None,
+                 target=None):
         assert placement in ("device", "host", "disk"), placement
         self.placement = placement
+        # the jax device loads land on (None: the default device); a
+        # pipeline stage's store targets that stage's chip
+        self.target = target
         self.host, self.device, self.disk = host, device, disk
         self.quant = quant
         self.fused_int4 = fused_int4
@@ -234,7 +238,7 @@ class TieredWeightStore:
             views = split_views(host_buf.view(np.uint8), man)
         dev = {}
         for name, arr in views.items():
-            dev[name] = jax.device_put(arr)
+            dev[name] = jax.device_put(arr, self.target)
         for a in dev.values():
             a.block_until_ready()
         self.sim_floor(man.total_bytes, t0)
@@ -266,7 +270,7 @@ class TieredWeightStore:
                     # unfused baseline: materialize fp32 weights first
                     out[base] = np.asarray(dequantize_int4(
                         arr, scale, jnp.float32, g))
-                    out[base] = jax.device_put(out[base])
+                    out[base] = jax.device_put(out[base], self.target)
             elif name.endswith("#s"):
                 continue
             else:

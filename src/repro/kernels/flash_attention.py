@@ -34,9 +34,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, :, 0, :]                               # (bq, dh)
-    k = k_ref[0, :, 0, :]                               # (bk, dh)
-    v = v_ref[0, :, 0, :]
+    q = q_ref[...]                                      # (bq, dh)
+    k = k_ref[...]                                      # (bk, dh)
+    v = v_ref[...]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
 
@@ -66,13 +66,16 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     @pl.when(ki == n_k - 1)
     def _out():
         denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / denom).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     q_offset: int = 0, block_q: int = 256,
-                    block_k: int = 256, interpret: bool = True):
-    """q (b, sq, h, dh); k/v (b, sk, hkv, dh) -> (b, sq, h, dh)."""
+                    block_k: int = 256, interpret: bool = False):
+    """q (b, sq, h, dh); k/v (b, sk, hkv, dh) -> (b, sq, h, dh).
+
+    The kernel runs on head-major copies, (b, h, s, dh), so that a block
+    is a (rows, dh) tile of one head, which the TPU tiles as it is."""
     b, sq, h, dh = q.shape
     _, sk, hkv, _ = k.shape
     g = h // hkv
@@ -82,24 +85,21 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     n_k = sk // block_k
     grid = (b, h, sq // block_q, n_k)
     scale = 1.0 / (dh ** 0.5)
+    q, k, v = (a.transpose(0, 2, 1, 3) for a in (q, k, v))
 
     kernel = functools.partial(_kernel, block_q=block_q, block_k=block_k,
                                n_k=n_k, causal=causal, window=window,
                                q_offset=q_offset, scale=scale)
-    return pl.pallas_call(
+    q_spec = pl.BlockSpec((None, None, block_q, dh),
+                          lambda bi, hi, qi, ki: (bi, hi, qi, 0))
+    kv_spec = pl.BlockSpec((None, None, block_k, dh),
+                           lambda bi, hi, qi, ki, g=g: (bi, hi // g, ki, 0))
+    out = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, dh),
-                         lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-            pl.BlockSpec((1, block_k, 1, dh),
-                         lambda bi, hi, qi, ki, g=g: (bi, ki, hi // g, 0)),
-            pl.BlockSpec((1, block_k, 1, dh),
-                         lambda bi, hi, qi, ki, g=g: (bi, ki, hi // g, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, 1, dh),
-                               lambda bi, hi, qi, ki: (bi, qi, hi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sq, h, dh), q.dtype),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, sq, dh), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
@@ -110,3 +110,4 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")) if not interpret else None,
     )(q, k, v)
+    return out.transpose(0, 2, 1, 3)

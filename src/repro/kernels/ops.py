@@ -1,16 +1,12 @@
 """jit'd dispatch wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels run in interpret mode for validation;
-on TPU they compile natively.  ``use_kernels(False)`` forces the pure-jnp
-reference path (used by the dry-run, whose compiled artifact must consist
-of ops the roofline analyzer models).
+The kernels compile natively; a caller that wants the Pallas interpreter
+(e.g. to validate on CPU) passes ``interpret=True`` through ``**kw``.
+``use_kernels(False)`` forces the pure-jnp reference path (used by the
+dry-run, whose compiled artifact must consist of ops the roofline analyzer
+models).
 """
 from __future__ import annotations
-
-from functools import partial
-
-import jax
-import jax.numpy as jnp
 
 from repro.kernels import ref as R
 from repro.kernels.decode_attention import decode_attention_kernel
@@ -24,14 +20,10 @@ def use_kernels(flag: bool):
     _STATE["enabled"] = flag
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def int4_matmul_op(x, packed, scale, **kw):
     if not _STATE["enabled"]:
         return R.int4_matmul_ref(x, packed, scale)
-    return int4_matmul(x, packed, scale, interpret=_interpret(), **kw)
+    return int4_matmul(x, packed, scale, **kw)
 
 
 def flash_attention_op(q, k, v, *, causal=True, window=0, q_offset=0, **kw):
@@ -39,12 +31,11 @@ def flash_attention_op(q, k, v, *, causal=True, window=0, q_offset=0, **kw):
         return R.flash_attention_ref(q, k, v, causal=causal, window=window,
                                      q_offset=q_offset)
     return flash_attention(q, k, v, causal=causal, window=window,
-                           q_offset=q_offset, interpret=_interpret(), **kw)
+                           q_offset=q_offset, **kw)
 
 
 def decode_attention_op(q, k_cache, v_cache, pos, **kw):
     if not _STATE["enabled"]:
         return R.decode_attention_ref(q[:, None], k_cache, v_cache,
                                       pos)[:, 0]
-    return decode_attention_kernel(q, k_cache, v_cache, pos,
-                                   interpret=_interpret(), **kw)
+    return decode_attention_kernel(q, k_cache, v_cache, pos, **kw)

@@ -10,15 +10,22 @@ from __future__ import annotations
 import jax
 
 
+def _auto(n: int):
+    """Auto axis types: the model code places arrays with sharding
+    constraints, which ``jax.make_mesh``'s default Explicit axes refuse."""
+    return (jax.sharding.AxisType.Auto,) * n
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_test_mesh(*, model: int = 4, data: int = 2):
     """Small mesh for subprocess tests (8 fake devices)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=_auto(2))
 
 
 def stage_devices(n_stages: int):

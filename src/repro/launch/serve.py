@@ -40,6 +40,7 @@ import time
 
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.spec import (EngineSpec, SpecError, add_spec_args,
                                 spec_from_args)
 
@@ -64,6 +65,7 @@ def build_parser() -> argparse.ArgumentParser:
 def main(argv=None):
     ap = build_parser()
     args = ap.parse_args(argv)
+    enable_compile_cache()
     base = None
     try:
         if args.spec_json:

@@ -50,7 +50,7 @@ def main():
     from repro.configs import get_config, scaled_down
     from repro.data import DataConfig, DataPipeline, SyntheticSource
     from repro.launch import sharding as S
-    from repro.launch.mesh import make_production_mesh
+    from repro.launch.mesh import make_production_mesh, make_test_mesh
     from repro.launch.steps import make_train_step
     from repro.models import Dist, build_model
     from repro.optim import AdamW
@@ -66,7 +66,7 @@ def main():
     elif n_dev >= 256:
         mesh = make_production_mesh()
     elif n_dev >= 8:
-        mesh = jax.make_mesh((n_dev // 4, 4), ("data", "model"))
+        mesh = make_test_mesh(model=4, data=n_dev // 4)
     else:
         mesh = None
     dist = S.make_dist(mesh) if mesh else Dist.local()

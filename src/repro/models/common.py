@@ -87,21 +87,13 @@ def pmean(x, axis):
     return lax.pmean(x, axis) if axis else x
 
 
-def _one_axis_size(a) -> int:
-    """``lax.axis_size`` with a jax<0.6 fallback: psum of the constant 1
-    constant-folds to the (static) axis size under shard_map."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(a)
-    return lax.psum(1, a)
-
-
 def axis_index(axis):
     if not axis:
         return jnp.int32(0)
     if isinstance(axis, (tuple, list)):
         idx = jnp.int32(0)
         for a in axis:
-            idx = idx * _one_axis_size(a) + lax.axis_index(a)
+            idx = idx * lax.axis_size(a) + lax.axis_index(a)
         return idx
     return lax.axis_index(axis)
 
@@ -112,9 +104,9 @@ def axis_size(axis):
     if isinstance(axis, (tuple, list)):
         n = 1
         for a in axis:
-            n *= _one_axis_size(a)
+            n *= lax.axis_size(a)
         return n
-    return _one_axis_size(axis)
+    return lax.axis_size(axis)
 
 
 # ---------------------------------------------------------------------------
@@ -176,35 +168,21 @@ def empty_partials(shape_ml, d, dtype=jnp.float32):
     return m, l, o
 
 
-def _abstract_type(x):
-    """``jax.typeof`` with a fallback for jax < 0.6 (no ``typeof``; avals
-    there carry no ``vma`` either, so callers degrade to a no-op)."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is not None:
-        return typeof(x)
-    return jax.core.get_aval(x)
-
-
 def match_vma(x, like):
     """Promote x's varying-manual-axes to match ``like`` (shard_map carries).
 
     Under shard_map, loop carries initialized with jnp.zeros are 'unvarying'
     while computed outputs vary over the mapped axes; lax.fori_loop/scan then
-    reject the carry.  No-op outside shard_map (and on jax versions without
-    the vma machinery).
+    reject the carry.  No-op outside shard_map.
     """
-    vma = getattr(_abstract_type(like), "vma", None)
+    vma = jax.typeof(like).vma
     if not vma:
         return x
     def fix(t):
-        cur = getattr(_abstract_type(t), "vma", frozenset())
-        missing = tuple(sorted(vma - cur))
+        missing = tuple(sorted(vma - jax.typeof(t).vma))
         if not missing:
             return t
-        try:
-            return lax.pcast(t, missing, to="varying")
-        except (AttributeError, TypeError):
-            return lax.pvary(t, missing)
+        return lax.pcast(t, missing, to="varying")
     return jax.tree.map(fix, x)
 
 

@@ -253,11 +253,8 @@ class Ctx:
 
 
 def _shard_map(ctx: Ctx, fn, in_specs, out_specs):
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:  # jax < 0.6: experimental namespace
-        from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=ctx.dist.mesh, in_specs=in_specs,
-                     out_specs=out_specs)
+    return jax.shard_map(fn, mesh=ctx.dist.mesh, in_specs=in_specs,
+                         out_specs=out_specs)
 
 
 # ===========================================================================

@@ -8,11 +8,17 @@ which makes M-RoPE coincide with 1-D RoPE (a property we test).
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 
 
-def rope_freqs(head_dim: int, theta: float):
+def rope_freqs(head_dim: int, theta: float) -> np.ndarray:
+    """(half,) f32 inverse frequencies, computed on the host: a caller that
+    runs eagerly and one under jit get the same constants (a TPU's own
+    pow and divide round differently from the compile-time folding, which
+    moved angles near position 63 by 2e-4)."""
     half = head_dim // 2
-    return 1.0 / (theta ** (jnp.arange(0, half, dtype=jnp.float32) / half))
+    exps = np.arange(0, half, dtype=np.float32) / np.float32(half)
+    return np.float32(1.0) / np.power(np.float32(theta), exps)
 
 
 def rope_angles(positions, head_dim: int, theta: float, mrope_sections=()):

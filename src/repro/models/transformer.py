@@ -263,20 +263,6 @@ def _angles(cfg: ModelConfig, positions):
     return rope_angles(positions, rope_dim, cfg.rope_theta)
 
 
-@jax.custom_jvp
-def _opt_barrier(ps):
-    """``lax.optimization_barrier`` with a differentiation rule for jax
-    versions that lack one (< 0.5): barrier the primals, pass tangents
-    through — the barrier is a scheduling hint, semantically identity."""
-    return lax.optimization_barrier(ps)
-
-
-@_opt_barrier.defjvp
-def _opt_barrier_jvp(primals, tangents):
-    (ps,), (ts,) = primals, tangents
-    return _opt_barrier(ps), ts
-
-
 def _run_stack(params, x, ctx: L.Ctx, caches, cfg: ModelConfig,
                pattern, remainder, remat: bool):
     aux0 = jnp.float32(0.0)
@@ -292,7 +278,7 @@ def _run_stack(params, x, ctx: L.Ctx, caches, cfg: ModelConfig,
         # out of the while loop, doubling resident param memory (observed on
         # jamba/deepseek: +100GiB/device).  TPU has native bf16 dots; the
         # barrier is a no-op for performance there.
-        ps = _opt_barrier(ps)
+        ps = lax.optimization_barrier(ps)
         new_cs = []
         for idx, spec in enumerate(pattern):
             x, nc, a = L.apply_layer(ps[idx], x, ctx,

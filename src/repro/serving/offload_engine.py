@@ -84,6 +84,7 @@ from repro.core.offload import DeviceStore, DiskStore
 from repro.core.pipeline import PipelineScheduler, StagedScheduler, ThreadPool
 from repro.core.tasks import Task, TaskType, Trace, _merged_busy
 from repro.core.transfer import TieredWeightStore, int4_roundtrip
+from repro.launch.mesh import stage_devices
 from repro.models import Dist, build_model
 from repro.models import layers as L
 from repro.models import moe as moe_mod
@@ -164,6 +165,88 @@ def quant_roundtrip_params(cfg: ModelConfig, params):
         "rem": tuple(do_tab(params["rem"][q], cfg.remainder[q], False)
                      for q in range(len(cfg.remainder))),
     }
+
+
+def unit_programs(cfg: ModelConfig, dist, spec: LayerSpec,
+                  kinds: Dict[str, str], *, max_len: int, kv_int4: bool):
+    """The (decode, prefill, chunk) programs the engine jits for one kind
+    of schedulable unit: plain functions of the unit's streamed weights,
+    the activation and its cache, so they can also be lowered from shapes
+    alone (tests/test_tpu_compile.py compiles them for a TPU)."""
+
+    def decode_fn(w, x, cache, pos, angles):
+        # INT4 KV already dequantized on the transfer thread
+        # (kvstore.load, live rows only) — the cache arrives at compute
+        # precision in every kv_mode.  kv_roundtrip hands the speculative
+        # verify pass the tier's lossy write-back, so its later queries
+        # attend the pass's earlier rows at the precision sequential
+        # decode would reload them at
+        ctx = L.Ctx(cfg=cfg, dist=dist, mode="decode", angles=angles,
+                    pos=pos, batch_size=x.shape[0],
+                    kv_roundtrip=kv_roundtrip_traceable if kv_int4 else None)
+        x, new_cache, _ = L.apply_layer(w, x, ctx, cache, spec)
+        # gather only the newly written sequence rows so KV_SAVE ships
+        # (b, s, ...) instead of the whole cache — s new rows per slot at
+        # pos..pos+s-1 (s=1 plain decode, k+1 for a speculative verify
+        # pass)
+        s = x.shape[1]
+        rows = {}
+        for name, kind in kinds.items():
+            leaf = new_cache[name]
+            if kind == "kv":
+                locs = pos.reshape(-1, 1) + jnp.arange(s)[None, :]
+                idx = locs.reshape((-1, s) + (1,) * (leaf.ndim - 2))
+                rows[name] = jnp.take_along_axis(
+                    leaf, idx.astype(jnp.int32), axis=1)
+            else:
+                rows[name] = leaf
+        return x, rows
+
+    def prefill_fn(w, x, angles):
+        ctx = L.Ctx(cfg=cfg, dist=dist, mode="prefill", angles=angles,
+                    cache_len=max_len, batch_size=x.shape[0])
+        x, new_cache, _ = L.apply_layer(w, x, ctx, None, spec)
+        return x, new_cache
+
+    def chunk_fn(w, x, pk, pv, angles, q_off):
+        # one prefill CHUNK: rows q_off..q_off+c-1 attend the engine-held
+        # fp32 prefix (earlier chunks' post-rope k/v) plus themselves —
+        # bit-identical to the same rows of a monolithic prefill
+        # (attention.chunk_prefill_attention).  Retraces per (prefix_len,
+        # chunk_len) shape pair, which the fixed chunk cap bounds.
+        ctx = L.Ctx(cfg=cfg, dist=dist, mode="prefill", angles=angles,
+                    batch_size=x.shape[0])
+        return L.apply_layer_chunk(w, x, ctx, pk, pv, q_off)
+
+    return decode_fn, prefill_fn, chunk_fn
+
+
+def head_programs(cfg: ModelConfig, dist):
+    """The (embed, head, speculative head) programs around the streamed
+    stack; they run on the device-resident embedding and final norm."""
+
+    def embed_fn(emb_p, tok, mode):
+        ctx = L.Ctx(cfg=cfg, dist=dist, mode=mode, batch_size=tok.shape[0])
+        return L.embed_tokens(emb_p, tok, ctx)
+
+    def head_fn(emb_p, fn_p, x):
+        ctx = L.Ctx(cfg=cfg, dist=dist, mode="decode",
+                    batch_size=x.shape[0])
+        x = L.rms_norm(x, fn_p["scale"], cfg.norm_eps)
+        return L.lm_head_argmax(emb_p, x[:, -1:], ctx)
+
+    def spec_head_fn(emb_p, fn_p, x):
+        # per-POSITION greedy argmax for the verify pass: reshape
+        # (b, s, d) -> (b*s, 1, d) so every position goes through the
+        # exact lm_head_argmax row arithmetic the plain head uses —
+        # per-row numerics identical, hence token parity
+        b, s, d = x.shape
+        ctx = L.Ctx(cfg=cfg, dist=dist, mode="decode", batch_size=b * s)
+        x = L.rms_norm(x, fn_p["scale"], cfg.norm_eps)
+        return L.lm_head_argmax(
+            emb_p, x.reshape(b * s, 1, d), ctx).reshape(b, s)
+
+    return embed_fn, head_fn, spec_head_fn
 
 
 class _StagedWeightStore:
@@ -343,7 +426,15 @@ class OffloadedServingEngine(SlotEngineBase):
         self.quant = plan.quant
         self.warm = plan.warm
         self.device = DeviceStore()
-        self.disk = DiskStore(plan.disk_root)
+        # the disk tier exists (and touches the filesystem) only when the
+        # plan places weights there
+        self.disk = (DiskStore(plan.disk_root) if plan.placement == "disk"
+                     else None)
+        # the jax device each stage's weights, KV and activations live on
+        # (one per chip, round-robin); None keeps a single-stage engine on
+        # the default device
+        self.stage_devices = (stage_devices(self.n_stages)
+                              if self.n_stages > 1 else [None])
         if self.n_stages > 1:
             # one tiered store per stage = one independent SimLink per
             # stage: each stage streams only its slice and the aggregate
@@ -356,8 +447,9 @@ class OffloadedServingEngine(SlotEngineBase):
                     fused_int4=plan.fused_int4,
                     block_bytes=plan.block_bytes,
                     n_io_threads=plan.n_io_threads,
-                    cold_reads=plan.cold_reads, sim_bw=plan.sim_bw)
-                 for _ in range(self.n_stages)],
+                    cold_reads=plan.cold_reads, sim_bw=plan.sim_bw,
+                    target=dev)
+                 for dev in self.stage_devices],
                 lambda key: self._stage_of_unit(self._unit_of_key(key)))
         else:
             self.weights = TieredWeightStore(
@@ -400,12 +492,10 @@ class OffloadedServingEngine(SlotEngineBase):
                 sum(self.weights.nbytes(u.key) for u in self.units)
                 // max(1, len(self.units)))
         if self.n_stages > 1:
-            from repro.launch.mesh import stage_devices
             self.sched = _MeshStagedScheduler(
                 self.stage_bounds, plan.pipeline, pools=self._stage_pools,
                 trace=self.trace, warm=self.warm,
-                depths=self._stage_depths,
-                devices=stage_devices(self.n_stages))
+                depths=self._stage_depths, devices=self.stage_devices)
         else:
             self.sched = PipelineScheduler(len(self.units), plan.pipeline,
                                            pool=pool, trace=self.trace,
@@ -480,10 +570,12 @@ class OffloadedServingEngine(SlotEngineBase):
         split further: the router stays on device (tiny; needed before
         any expert prefetch), each expert becomes its own tiered buffer so
         decode can load just the routed union (paper Appendix C.4).
-        Main thread, build time only."""
+        Main thread, build time only.  The resident tensors live on the
+        first stage's device, where the embedding and the head run."""
         self.resident = {
-            "embed": jax.device_put(params["embed"]),
-            "final_norm": jax.device_put(params["final_norm"]),
+            "embed": jax.device_put(params["embed"], self.stage_devices[0]),
+            "final_norm": jax.device_put(params["final_norm"],
+                                         self.stage_devices[0]),
         }
         cfg = self.cfg
         for p in range(cfg.num_periods):
@@ -505,7 +597,10 @@ class OffloadedServingEngine(SlotEngineBase):
         if spec.ffn == MOE:
             u.moe = True
             m = self.cfg.moe
-            u.router = jax.device_put(jnp.asarray(tensors.pop("wg")))
+            dev = self.stage_devices[
+                self._stage_of_unit(len(self.units)) if self.n_stages > 1
+                else 0]
+            u.router = jax.device_put(tensors.pop("wg"), dev)
             wga = tensors.pop("w_gate")
             wup = tensors.pop("w_up")
             wdn = tensors.pop("w_down")
@@ -542,7 +637,8 @@ class OffloadedServingEngine(SlotEngineBase):
                     shapes[lo:hi], kk[lo:hi], b_max=self.b_max,
                     max_len=self.max_len,
                     kv_mode=self.quant_policy.kv_mode,
-                    link=self.weights.stores[s].link)
+                    link=self.weights.stores[s].link,
+                    target=self.stage_devices[s])
                  for s, (lo, hi) in enumerate(self.stage_bounds)],
                 self.stage_bounds)
         else:
@@ -561,86 +657,20 @@ class OffloadedServingEngine(SlotEngineBase):
             sig = (u.group, u.q)
             if sig in self._decode_fns:
                 continue
-            kinds = self.kv_kinds[j]
             # MoE units run the mixer through apply_layer with a DENSE ffn
             # spec: the base params carry no dense "w_gate", so the ffn
             # half no-ops and the MoE ffn runs in _compute_moe (expert
             # loads overlap compute there).
             spec = (LayerSpec(u.spec.mixer) if u.moe else u.spec)
-
-            def decode_fn(w, x, cache, pos, angles, spec=spec, kinds=kinds):
-                # INT4 KV already dequantized on the transfer thread
-                # (kvstore.load, live rows only) — the cache arrives at
-                # compute precision in every kv_mode.  kv_roundtrip hands
-                # the speculative verify pass the tier's lossy write-back,
-                # so its later queries attend the pass's earlier rows at
-                # the precision sequential decode would reload them at
-                ctx = L.Ctx(cfg=cfg, dist=dist, mode="decode", angles=angles,
-                            pos=pos, batch_size=x.shape[0],
-                            kv_roundtrip=kv_roundtrip_traceable
-                            if self.quant_policy.kv_mode == "int4" else None)
-                x, new_cache, _ = L.apply_layer(w, x, ctx, cache, spec)
-                # gather only the newly written sequence rows so KV_SAVE
-                # ships (b, s, ...) instead of the whole cache — s new
-                # rows per slot at pos..pos+s-1 (s=1 plain decode, k+1
-                # for a speculative verify pass)
-                s = x.shape[1]
-                rows = {}
-                for name, kind in kinds.items():
-                    leaf = new_cache[name]
-                    if kind == "kv":
-                        locs = pos.reshape(-1, 1) + jnp.arange(s)[None, :]
-                        idx = locs.reshape((-1, s) + (1,) * (leaf.ndim - 2))
-                        rows[name] = jnp.take_along_axis(
-                            leaf, idx.astype(jnp.int32), axis=1)
-                    else:
-                        rows[name] = leaf
-                return x, rows
-
-            def prefill_fn(w, x, angles, spec=spec):
-                ctx = L.Ctx(cfg=cfg, dist=dist, mode="prefill", angles=angles,
-                            cache_len=self.max_len, batch_size=x.shape[0])
-                x, new_cache, _ = L.apply_layer(w, x, ctx, None, spec)
-                return x, new_cache
-
-            def chunk_fn(w, x, pk, pv, angles, q_off):
-                # one prefill CHUNK: rows q_off..q_off+c-1 attend the
-                # engine-held fp32 prefix (earlier chunks' post-rope k/v)
-                # plus themselves — bit-identical to the same rows of a
-                # monolithic prefill (attention.chunk_prefill_attention).
-                # Retraces per (prefix_len, chunk_len) shape pair, which
-                # the fixed chunk cap bounds.
-                ctx = L.Ctx(cfg=cfg, dist=dist, mode="prefill",
-                            angles=angles, batch_size=x.shape[0])
-                return L.apply_layer_chunk(w, x, ctx, pk, pv, q_off)
-
+            decode_fn, prefill_fn, chunk_fn = unit_programs(
+                cfg, dist, spec, self.kv_kinds[j], max_len=self.max_len,
+                kv_int4=self.quant_policy.kv_mode == "int4")
             self._decode_fns[sig] = jax.jit(decode_fn)
             self._prefill_fns[sig] = jax.jit(prefill_fn)
             self._chunk_fns[sig] = jax.jit(chunk_fn)
             if u.moe:
                 self._moe_fns[sig] = self._jit_moe_fns()
-
-        def embed_fn(emb_p, tok, mode):
-            ctx = L.Ctx(cfg=cfg, dist=dist, mode=mode, batch_size=tok.shape[0])
-            return L.embed_tokens(emb_p, tok, ctx)
-
-        def head_fn(emb_p, fn_p, x):
-            ctx = L.Ctx(cfg=cfg, dist=dist, mode="decode",
-                        batch_size=x.shape[0])
-            x = L.rms_norm(x, fn_p["scale"], cfg.norm_eps)
-            return L.lm_head_argmax(emb_p, x[:, -1:], ctx)
-
-        def spec_head_fn(emb_p, fn_p, x):
-            # per-POSITION greedy argmax for the verify pass: reshape
-            # (b, s, d) -> (b*s, 1, d) so every position goes through the
-            # exact lm_head_argmax row arithmetic the plain head uses —
-            # per-row numerics identical, hence token parity
-            b, s, d = x.shape
-            ctx = L.Ctx(cfg=cfg, dist=dist, mode="decode", batch_size=b * s)
-            x = L.rms_norm(x, fn_p["scale"], cfg.norm_eps)
-            return L.lm_head_argmax(
-                emb_p, x.reshape(b * s, 1, d), ctx).reshape(b, s)
-
+        embed_fn, head_fn, spec_head_fn = head_programs(cfg, dist)
         self._embed = jax.jit(embed_fn, static_argnums=(2,))
         self._head = jax.jit(head_fn)
         self._spec_head = jax.jit(spec_head_fn)
@@ -878,6 +908,9 @@ class OffloadedServingEngine(SlotEngineBase):
                        shared_term)
 
     def finalize(self, i: int, x):
+        if self.n_stages > 1:
+            # the last stage's activation goes back to the head's device
+            x = jax.device_put(x, self.stage_devices[0])
         if self._chunk_step is not None:
             x_dec, x_ck = x
             _, _, final = self._chunk_step

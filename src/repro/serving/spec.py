@@ -371,7 +371,9 @@ class EngineSpec:
                 trace=None) -> "ResolvedPlan":
         """Materialize every auto field against ``budget`` (paper §3.5 /
         Eq. 1 via ``core.autoconfig``), recording each decision's why in
-        the plan's provenance map.
+        the plan's provenance map.  Without a ``budget`` the device
+        capacity is the one the backend reports
+        (``MemoryBudget.for_device``).
 
         ``trace`` (a recorded ``core.tasks.Trace``, e.g. loaded with
         ``Trace.from_json``) switches depth resolution from the
@@ -384,7 +386,7 @@ class EngineSpec:
                                            replay_depth_decision,
                                            serving_depth_decision)
         self.validate()
-        budget = budget or MemoryBudget()
+        budget = budget or MemoryBudget.for_device()
         cfg = self.model_config()
         prov: Dict[str, str] = {}
         cap = offload_capability(cfg)

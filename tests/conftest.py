@@ -2,12 +2,15 @@ import os
 
 import pytest
 
+from repro.launch.compile_cache import enable_compile_cache
+
 # persistent XLA compilation cache: the suite is compile-bound on CPU, so
 # repeat runs (local dev loops, warm CI caches) skip most of the work.
-# Opt out with JAX_COMPILATION_CACHE_DIR="".
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      "/tmp/pipo_jax_compile_cache")
+# The directory follows the entry points' rule (launch/compile_cache.py):
+# JAX_COMPILATION_CACHE_DIR when set ("" opts out), else the checkout's
+# .jax_cache/.
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
+enable_compile_cache()
 
 
 def pytest_addoption(parser):

@@ -78,3 +78,17 @@ def test_mla_decode_matches_naive():
     ref = jnp.moveaxis(jnp.einsum("bhqs,bsr->bhqr", p, cc2[:, :pos + 1]), 2, 1)
     np.testing.assert_allclose(np.asarray(ctx), np.asarray(ref), atol=2e-5)
     assert bool((cc2[:, pos] == cn[:, 0]).all())
+
+
+def test_rope_angles_same_eager_and_jitted():
+    """The offloaded engine computes its rope angles eagerly between
+    layer programs while the resident engine computes them inside its
+    jit: both must get the same bits (the inverse frequencies are host
+    constants, not a pow and divide rounded by the device)."""
+    from repro.models.rope import rope_angles
+    pos = jnp.arange(2048)
+    eager = rope_angles(pos, 64, 10000.0)
+    jitted = jax.jit(lambda p: rope_angles(p, 64, 10000.0))(pos)
+    folded = jax.jit(lambda: rope_angles(jnp.arange(2048), 64, 10000.0))()
+    np.testing.assert_array_equal(np.asarray(eager), np.asarray(jitted))
+    np.testing.assert_array_equal(np.asarray(eager), np.asarray(folded))

@@ -21,7 +21,8 @@ from repro.configs import ASSIGNED, scaled_down
 from repro.launch.sharding import make_dist, param_pspecs, batch_pspecs
 from repro.models import build_model, Dist
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import make_test_mesh
+mesh = make_test_mesh(model=4, data=2)
 
 failures = []
 for arch in ("granite-8b", "gemma3-4b", "deepseek-v3-671b", "jamba-1.5-large-398b", "mamba2-1.3b"):
@@ -79,6 +80,8 @@ def test_distributed_parity_8dev():
     env = dict(os.environ)
     root = Path(__file__).resolve().parent.parent
     env["PYTHONPATH"] = str(root / "src")
+    # fake CPU devices only: the child never loads an accelerator runtime
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", SCRIPT], env=env,
                        capture_output=True, text=True, timeout=1200)
     print(r.stdout)

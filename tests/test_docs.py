@@ -25,3 +25,26 @@ def test_docs_exist_and_linked():
     readme = (ROOT / "README.md").read_text()
     assert "docs/ARCHITECTURE.md" in readme
     assert "docs/BENCHMARKS.md" in readme
+
+
+def test_bench_run_fails_when_any_scenario_raises(capsys):
+    """benchmarks/run.py exits non-zero when a scenario raised, in a
+    full run as well as for a scenario requested by name."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "_pipo_bench_run_exit", ROOT / "benchmarks" / "run.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+
+    def fine():
+        """passes"""
+
+    def broken():
+        """raises"""
+        raise RuntimeError("scenario failed")
+
+    mod.BENCHES = [fine, broken]
+    assert mod.main([]) == 1
+    assert mod.main(["broken"]) == 1
+    assert mod.main(["fine"]) == 0
+    assert "broken_ERROR" in capsys.readouterr().out

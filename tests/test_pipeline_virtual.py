@@ -605,3 +605,28 @@ def test_trace_report_accounts_busy_time():
                - model.n * COSTS[TaskType.COMPUTE]) < 1e-9
     assert rep["bubble_s"] > 0
     assert abs(rep["compute_util"] + rep["bubble_frac"] - 1.0) < 1e-9
+
+
+def test_drop_kv_preloads_reraises_a_failed_preload():
+    """A discarded preload's RESULT is dropped, its exception is not: a
+    failed transfer surfaces at drop time (after every other in-flight
+    load has been waited out) instead of vanishing."""
+    from repro.core.pipeline import PipelineScheduler, VirtualPool
+    from fake_model import FakeModel, cost_fn
+
+    class FailingPreloads(FakeModel):
+        def load_kv(self, i, j):
+            if i >= 1:                       # the next call's preloads
+                raise RuntimeError("kv transfer failed")
+            return super().load_kv(i, j)
+
+    model = FailingPreloads(3)
+    pool = VirtualPool(3, cost_fn=cost_fn)
+    sched = PipelineScheduler(model.n, "performance", pool=pool,
+                              trace=pool.trace, warm=True, depth=4)
+    sched.generate(model, lambda i: 0, 1)
+    assert sched._kv_tasks
+    with pytest.raises(RuntimeError, match="kv transfer failed"):
+        sched.drop_kv_preloads()
+    assert not sched._kv_tasks
+    sched.shutdown()

@@ -738,3 +738,61 @@ def test_pp_stage_count_clamps_to_units():
     assert eng.plan.stages == 2
     assert "clamped" in eng.plan.provenance["stages"]
     _serve(eng, _prompts(cfg, 1), max_new=2)
+
+
+_PP_DEVICES_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax
+import numpy as np
+from repro.configs import get_config, scaled_down
+from repro.serving import EngineSpec, Request, create_engine
+
+devs = jax.devices()
+assert len(devs) == 4, devs
+cfg = scaled_down(get_config("tinyllama-1.1b"), num_layers=8, num_periods=8)
+rng = np.random.default_rng(0)
+prompts = [rng.integers(0, cfg.vocab_size, (6 + i,)).astype(np.int32)
+           for i in range(3)]
+
+def serve(stages):
+    eng = create_engine(EngineSpec(
+        arch="tinyllama-1.1b", cfg=cfg, offload=True, placement="host",
+        pipeline="performance", b_max=2, max_len=64,
+        stages=stages).resolve())
+    if stages > 1:
+        held = lambda tree: {d for a in jax.tree.leaves(tree)
+                             for d in a.devices()}
+        assert held(eng.resident) == {devs[0]}, held(eng.resident)
+        for s, (lo, hi) in enumerate(eng.stage_bounds):
+            assert held(eng.weights.stores[s].load(eng.units[lo].key)) \
+                == {devs[s]}, s
+            assert held(eng.kvstore.stores[s].load(0)) == {devs[s]}, s
+    for i, p in enumerate(prompts):
+        eng.submit(Request(rid=i, prompt=p, max_new=4))
+    out = {r.rid: r.out for r in eng.run()}
+    eng.shutdown()
+    return out
+
+assert serve(4) == serve(1)
+print("STAGED_DEVICES_OK")
+"""
+
+
+def test_pp_stages_live_on_their_own_devices():
+    """With one device per stage (4 virtual CPU devices, in a child
+    process: the device count is fixed at JAX start-up), stage s's
+    weight and KV loads land on device s, the resident embedding and
+    final norm on device 0 where the head runs, and the tokens equal the
+    single-stage engine's."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+    env = dict(os.environ)
+    env["PYTHONPATH"] = str(Path(__file__).resolve().parent.parent / "src")
+    env["JAX_PLATFORMS"] = "cpu"
+    r = subprocess.run([sys.executable, "-c", _PP_DEVICES_SCRIPT], env=env,
+                       capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0 and "STAGED_DEVICES_OK" in r.stdout, \
+        r.stderr[-3000:]

@@ -471,3 +471,48 @@ def test_stages_validation():
         _spec(offload=True, stages=0).validate()
     with pytest.raises(ValueError, match="stage_axis"):
         _spec(offload=True, stages=2, stage_axis="tensor").validate()
+
+
+class _ReportingDevice:
+    """A device stand-in whose backend reports ``stats``."""
+
+    def __init__(self, stats):
+        self._stats = stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+def test_budget_device_capacity_from_backend():
+    """The device budget is the capacity the backend reports; a backend
+    that reports none (the CPU) keeps the RTX3060 default, so CPU plans
+    stay as they were."""
+    v5e = MemoryBudget.for_device(_ReportingDevice({"bytes_limit": 16 << 30}))
+    assert v5e.device == 16 << 30
+    assert v5e.host == MemoryBudget.host
+    assert MemoryBudget.for_device(_ReportingDevice(None)) == MemoryBudget()
+    assert MemoryBudget.for_device(_ReportingDevice({})) == MemoryBudget()
+    plan = _spec(offload=True).resolve()
+    assert plan.device_budget == MemoryBudget.for_device().device
+    bigger = _spec(offload=True).resolve(budget=v5e)
+    assert bigger.device_budget == 16 << 30
+
+
+def test_compile_cache_directory(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins when set (JAX reads it; nothing is
+    set in code); unset, the cache is the checkout's fixed .jax_cache."""
+    import jax
+    from repro.launch import compile_cache as cc
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        jax.config.update("jax_compilation_cache_dir", "untouched")
+        monkeypatch.setenv(cc.ENV, "/some/dir")
+        assert cc.enable_compile_cache() == "/some/dir"
+        assert jax.config.jax_compilation_cache_dir == "untouched"
+        monkeypatch.delenv(cc.ENV)
+        path = cc.enable_compile_cache()
+        assert path == str(cc.CHECKOUT_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == path
+        assert (cc.CHECKOUT_CACHE_DIR.parent / "chip_smoke.py").exists()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

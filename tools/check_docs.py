@@ -65,7 +65,7 @@ FLAG_DEF_RE = re.compile(
 # where CLI flags are defined (argparse entry points)
 CLI_SOURCES = [*sorted((ROOT / "src" / "repro" / "launch").glob("*.py")),
                *sorted((ROOT / "benchmarks").glob("*.py")),
-               ROOT / "tests" / "conftest.py"]
+               ROOT / "chip_smoke.py", ROOT / "tests" / "conftest.py"]
 # argparse/pytest built-ins the docs may reference without defining
 FLAG_ALLOWLIST = {"--help"}
 
@@ -231,7 +231,9 @@ def main() -> int:
     if not any(md.name == "BENCHMARKS.md" for md in DOC_FILES):
         errors.append("docs/BENCHMARKS.md missing")
 
-    env = {**os.environ, "PYTHONPATH": "src"}
+    # the dry-runs only parse and resolve; they stay off any accelerator,
+    # whose chip belongs to one process at a time
+    env = {**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"}
     seen = set()
     for cmd in commands:
         dry = dry_form(cmd)
