@@ -1,0 +1,15 @@
+"""Host-to-device weight rate over the link phases alone: the bytes of
+the weight loads' ``weight_load.put`` phases (which carry them) over the
+merged seconds of their ``put`` and ``ready`` phases (host clock).  Next
+to ``weight_h2d_gbps``, which spans whole loads, the difference is what
+staging host views and dequantizing cost."""
+from trace_reduce import merge
+
+KINDS = ("weight_load.put", "weight_load.ready")
+
+
+def read(run):
+    ev = [e for e in run.host_events if e.kind in KINDS]
+    busy = sum(t - s for s, t in merge((e.t_start, e.t_end) for e in ev))
+    nbytes = sum(e.nbytes for e in ev)
+    return nbytes / busy / 1e9 if busy and nbytes else None

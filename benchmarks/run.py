@@ -51,6 +51,16 @@ def emit(name: str, us_per_call: float, derived: str = ""):
     print(row, flush=True)
 
 
+def _main_share(rep, part: str) -> float:
+    """Share of the main thread's window spent in layer compute
+    (``part="compute"``) or waiting on any producer (``"wait"``), from
+    ``Trace.report()["main"]`` (host clock)."""
+    share = rep["main"]["share"]
+    if part == "compute":
+        return share["compute"]
+    return sum(v for k, v in share.items() if k.startswith("wait."))
+
+
 def _bench_cfg(layers=4, d=256, ff=1024, vocab=2048):
     from repro.configs.base import ATTN, DENSE, LayerSpec, ModelConfig
     return ModelConfig(name="bench", num_layers=layers, d_model=d,
@@ -257,8 +267,8 @@ def serving_offload():
         emit(f"serving_offload_{name}", step_s * 1e6,
              f"decode_tok_s={tok_s:.2f};"
              f"step_ms={step_s * 1e3:.1f};"
-             f"util={rep['compute_util']:.2f};"
-             f"bubble={rep['bubble_frac']:.2f}")
+             f"main_compute={_main_share(rep, 'compute'):.2f};"
+             f"main_wait={_main_share(rep, 'wait'):.2f}")
     emit("serving_offload_speedup", 0.0,
          f"perf_vs_seq={results['warm'][0] / max(1e-9, results['sequential'][0]):.2f}x;"
          f"warm_vs_cold={results['warm'][0] / max(1e-9, results['cold'][0]):.2f}x;"
@@ -379,8 +389,8 @@ def serving_offload_depth():
             emit(f"serving_offload_depth_{tag}_d{depth}", step_s * 1e6,
                  f"decode_tok_s={tok_s:.2f};"
                  f"step_ms={step_s * 1e3:.1f};"
-                 f"util={rep['compute_util']:.2f};"
-                 f"bubble={rep['bubble_frac']:.2f}")
+                 f"main_compute={_main_share(rep, 'compute'):.2f};"
+                 f"main_wait={_main_share(rep, 'wait'):.2f}")
     emit("serving_offload_depth_summary", 0.0,
          f"fp32_d2_vs_d1={results[('fp32', 1)] / results[('fp32', 2)]:.2f}x;"
          f"fp32_d3_vs_d1={results[('fp32', 1)] / results[('fp32', 3)]:.2f}x;"
@@ -425,8 +435,8 @@ def serving_kv_quant():
                  f"step_ms={step_s * 1e3:.1f};"
                  f"kv_KB_per_load={kv_kb_load:.0f};"
                  f"slab_KB={slab_kb:.0f};"
-                 f"util={rep['compute_util']:.2f};"
-                 f"bubble={rep['bubble_frac']:.2f}")
+                 f"main_compute={_main_share(rep, 'compute'):.2f};"
+                 f"main_wait={_main_share(rep, 'wait'):.2f}")
     emit("serving_kv_quant_summary", 0.0,
          f"int4_vs_fp32_d1="
          f"{results[('fp32', 1)] / results[('int4', 1)]:.2f}x;"
@@ -780,7 +790,7 @@ def serving_pp():
             ratio = tok_s / max(1e-9, base[tag][0])
             emit(f"serving_pp_s{stages}_{tag}", step_s * 1e6,
                  f"decode_tok_s={tok_s:.2f};step_ms={step_s * 1e3:.1f};"
-                 f"util={rep['compute_util']:.2f};"
+                 f"main_compute={_main_share(rep, 'compute'):.2f};"
                  f"vs_s1={ratio:.2f}x;"
                  f"bit_exact={int(tokens == base[tag][1])}")
             assert tokens == base[tag][1], \
@@ -923,8 +933,8 @@ def run_spec_scenario(path: str):
                f"engine={plan.engine};placement={plan.placement};"
                f"depth={plan.depth}")
     if rep:
-        derived += (f";util={rep['compute_util']:.2f};"
-                    f"bubble={rep['bubble_frac']:.2f}")
+        derived += (f";main_compute={_main_share(rep, 'compute'):.2f};"
+                    f"main_wait={_main_share(rep, 'wait'):.2f}")
     emit(f"spec_{plan.arch}{'_scaled' if plan.scaled else ''}",
          step_s * 1e6, derived)
 

@@ -156,10 +156,9 @@ def mismatches(a, b):
 def report_pipeline(tag: str, eng):
     rep = eng.pipeline_report()
     busy = {k: round(v["busy_s"], 3) for k, v in rep["per_kind"].items()}
-    log(f"[{tag}] pipeline depth={eng.sched.depth} "
-        f"compute_util={rep['compute_util']:.3f} "
-        f"bubble_frac={rep['bubble_frac']:.3f} busy_s={busy} "
-        f"(host-clock trace spans; smoke timing)")
+    split = {k: round(v, 3) for k, v in rep["main"]["share"].items()}
+    log(f"[{tag}] pipeline depth={eng.sched.depth} main thread {split} "
+        f"busy_s={busy} (host-clock trace spans; smoke timing)")
 
 
 def engine_spec(seed: int, **fields):

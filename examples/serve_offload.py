@@ -34,7 +34,7 @@ def main():
     dt = time.perf_counter() - t0
 
     total_new = sum(len(r.out) for r in done)
-    ttfts = [r.t_first - r.t_submit for r in done]
+    ttfts = [r.t_first_token - r.t_submit for r in done]
     print(f"requests completed : {len(done)}/10")
     print(f"engine stats       : {eng.stats}")
     print(f"decode steps shared: {eng.stats['decode_steps']} "

@@ -63,6 +63,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.tasks import phase
+
 __all__ = [
     "TieredKVStore", "PhasedKVExtents", "KV_GROUP", "kv_group",
     "kv_eligible", "quantize_kv_rows", "dequantize_kv_rows",
@@ -397,18 +399,24 @@ class TieredKVStore:
         return out
 
     def _put_padded(self, arr: np.ndarray, lb: int, ll: int, seq: bool):
+        """One full-precision leaf's live rows -> a device slab: the host
+        copy (``stage``), the transfer of the live bytes (``put``) and the
+        device-side zero pad (``pad``), each a phase of the KV_LOAD."""
         sl = arr[:lb, :ll] if seq else arr[:lb]
         if sl.shape == arr.shape:
-            return jax.device_put(arr, self.target)
-        if seq:
-            ll_b = self._bucket_len(ll)
-            rows = jax.device_put(self._bucketed(arr, lb, ll, ll_b),
-                                  self.target)
+            with phase("put", arr.nbytes):
+                return jax.device_put(arr, self.target)
+        ll_b = self._bucket_len(ll) if seq else None
+        with phase("stage"):
+            host = (self._bucketed(arr, lb, ll, ll_b) if seq
+                    else np.ascontiguousarray(sl))
+        with phase("put", sl.nbytes):
+            rows = jax.device_put(host, self.target)
+        with phase("pad"):
             dev = jnp.zeros(arr.shape, rows.dtype, device=self.target)
-            return dev.at[:lb, :ll_b].set(rows)
-        rows = jax.device_put(np.ascontiguousarray(sl), self.target)
-        dev = jnp.zeros(arr.shape, rows.dtype, device=self.target)
-        return dev.at[tuple(slice(0, s) for s in sl.shape)].set(rows)
+            if seq:
+                return dev.at[:lb, :ll_b].set(rows)
+            return dev.at[tuple(slice(0, s) for s in sl.shape)].set(rows)
 
     def load(self, j: int, live_b: Optional[int] = None,
              live_len: Optional[int] = None) -> Dict[str, Any]:
@@ -433,22 +441,27 @@ class TieredKVStore:
             leaf = self._units[j][name]
             if isinstance(leaf, _QuantLeaf):
                 ll_b = self._bucket_len(ll)
-                packed = jax.device_put(
-                    self._bucketed(leaf.packed, lb, ll, ll_b), self.target)
-                scale = jax.device_put(
-                    self._bucketed(leaf.scale, lb, ll, ll_b), self.target)
+                with phase("stage"):
+                    hp = self._bucketed(leaf.packed, lb, ll, ll_b)
+                    hs = self._bucketed(leaf.scale, lb, ll, ll_b)
+                with phase("put", leaf.packed[:lb, :ll].nbytes
+                           + leaf.scale[:lb, :ll].nbytes):
+                    packed = jax.device_put(hp, self.target)
+                    scale = jax.device_put(hs, self.target)
                 full = (self.b_max, self.max_len) + m.feat
-                out[name] = _dequant_pad_rows(packed, scale, leaf.group,
-                                              full, m.dtype)
+                with phase("pad"):
+                    out[name] = _dequant_pad_rows(packed, scale, leaf.group,
+                                                  full, m.dtype)
                 self.dequant_bytes_total += lb * ll \
                     * int(np.prod(m.feat)) * np.dtype(m.dtype).itemsize
             else:
                 out[name] = self._put_padded(leaf.arr, lb, ll,
                                              seq=m.kind == "kv")
-        for a in out.values():
-            a.block_until_ready()
-        if self.link is not None:
-            self.link.floor(self.load_nbytes(j, lb, ll), t0)
+        with phase("ready"):
+            for a in out.values():
+                a.block_until_ready()
+            if self.link is not None:
+                self.link.floor(self.load_nbytes(j, lb, ll), t0)
         return out
 
     # ---- saves (transfer-pool thread) --------------------------------------

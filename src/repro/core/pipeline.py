@@ -32,10 +32,12 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
 from dataclasses import dataclass, field
 from typing import Callable, Dict, Optional
 
-from repro.core.tasks import Task, TaskType, Trace, VirtualClock
+from repro.core.tasks import (Task, TaskType, Trace, VirtualClock, annotate,
+                              running)
 
 PIPELINE_MODES = ("performance", "memory", "sequential")
 
@@ -57,7 +59,8 @@ class ThreadPool:
         self._stop = False
         self._lock = threading.Lock()
         self._threads = [threading.Thread(target=self._worker,
-                                          args=(f"pool-{i}",), daemon=True)
+                                          args=(f"pool-{i}",), daemon=True,
+                                          name=f"pool-{i}")
                          for i in range(n_threads)]
         for t in self._threads:
             t.start()
@@ -66,7 +69,6 @@ class ThreadPool:
         """Enqueue a task (submitter thread; non-blocking).  Lower
         priority values run first; KV-saves use priority 1 so loads win
         ties (paper §3.2.1)."""
-        import time
         task.t_submit = time.perf_counter()
         with self._lock:
             self._seq += 1
@@ -74,18 +76,25 @@ class ThreadPool:
         return task
 
     def _worker(self, name: str):
+        """Pull and run tasks (pool thread).  Each task runs as this
+        thread's current task (its transfer phases record under it), and
+        its time in the queue is recorded as ``queue.<kind>``."""
         while True:
             prio, _, task = self._q.get()
             if task is None:
                 return
-            task.run()
+            with running(task, self.trace, name):
+                task.run()
+            self.trace.record(f"queue.{task.kind.value}", task.name,
+                              task.t_submit, task.t_start, name)
             self.trace.add(task, name)
             self._q.task_done()
 
     def run_on_main(self, task: Task) -> Task:
         """Compute tasks execute synchronously on the caller (main)
         thread — blocking until the task body returns."""
-        task.run()
+        with annotate(task.kind.value, task.name, task.nbytes):
+            task.run()
         self.trace.add(task, "main")
         if task.error is not None:
             raise task.error
@@ -293,7 +302,7 @@ class PipelineScheduler:
         t.stage = self.stage
         self.pool.submit(t, priority)
         if self.mode == "sequential":
-            t.wait()
+            t.wait(self.trace)
         return t
 
     # -- warm-pipeline maintenance (main thread) ----------------------------
@@ -313,7 +322,7 @@ class PipelineScheduler:
         err = None
         for t in tasks:
             try:
-                t.wait()
+                t.wait(self.trace)
             except Exception as e:  # wait out the rest, then re-raise
                 err = err or e
         if err is not None:
@@ -325,7 +334,7 @@ class PipelineScheduler:
         is itself a bubble); callers that read or write KV storage outside
         the pipeline must drain first."""
         for t in self._save_tasks.values():
-            t.wait()
+            t.wait(self.trace)
         self._save_tasks.clear()
 
     def prime_weights(self, model, count: Optional[int] = None) -> int:
@@ -406,7 +415,7 @@ class PipelineScheduler:
                 if not blocking and not prev_save.done.is_set():
                     return
                 save_tasks.pop((i - 1, j))
-                prev_save.wait()
+                prev_save.wait(self.trace)
             kv_tasks[(i, j)] = self._submit(
                 TaskType.KV_LOAD, f"kv[{i},{ub + j}]",
                 lambda i=i, j=j: model.load_kv(i, j),
@@ -453,10 +462,10 @@ class PipelineScheduler:
                 submit_kv(gi, j)                       # no-op if advanced
 
                 # --- SynchronizeLoadTask(i, j) -----------------------------
-                weights = w_tasks.pop(j).wait()
+                weights = w_tasks.pop(j).wait(self.trace)
                 kv = None
                 if model.is_mha(j):
-                    kv = kv_tasks.pop((gi, j)).wait()
+                    kv = kv_tasks.pop((gi, j)).wait(self.trace)
 
                 if self.mode == "performance":
                     # Preload: each window load starts only after the one
@@ -484,7 +493,7 @@ class PipelineScheduler:
                                               if kv_save_nbytes_of else 0))
                     save_tasks[(gi, j)] = st
                     if self.mode in ("memory", "sequential"):
-                        st.wait()
+                        st.wait(self.trace)
 
                 model.release_weights(j, weights)
             outputs.append(model.finalize(it, x))

@@ -65,7 +65,9 @@ class ReplayError(ValueError):
 def _parse(name: str) -> Optional[Tuple[str, Optional[int], int]]:
     """(kind, iteration, layer) from a scheduler task name; None for
     names the scheduler didn't mint (e.g. MoE expert loads submitted
-    from inside compute callbacks)."""
+    from inside compute callbacks).  Callers parse the work events only
+    (``Trace.work_events``): a wait, phase or queue span carries its
+    task's name but is not that task."""
     m = _W_RE.match(name)
     if m:
         return "w", None, int(m.group(1))
@@ -86,13 +88,13 @@ def step_boundaries(trace: Trace) -> List[float]:
     gap between consecutive boundaries."""
     tails: Dict[int, float] = {}
     n = 0
-    for e in trace.events():
+    for e in trace.work_events():
         p = _parse(e.name)
         if p is not None and p[0] == "c":
             n = max(n, p[2] + 1)
     if n == 0:
         return []
-    for e in trace.events():
+    for e in trace.work_events():
         p = _parse(e.name)
         if p is not None and p[0] == "c" and p[2] == n - 1:
             tails[p[1]] = e.t_end
@@ -105,7 +107,7 @@ def step_times(trace: Trace) -> List[float]:
     b = step_boundaries(trace)
     if not b:
         return []
-    evs = trace.events()
+    evs = trace.work_events()
     t0 = min(e.t_start for e in evs) if evs else 0.0
     return [b[0] - t0] + [b[k] - b[k - 1] for k in range(1, len(b))]
 
@@ -172,7 +174,7 @@ class TraceProfile:
         meta = trace.meta
         parsed = []
         n_units = int(meta.get("n_units") or 0)
-        for e in trace.events():
+        for e in trace.work_events():
             p = _parse(e.name)
             if p is None:
                 continue

@@ -15,10 +15,33 @@ Clock seam: all timestamps flow through a ``Clock`` so the scheduler can
 run against a ``VirtualClock`` (deterministic discrete-event timeline, no
 sleeps) in tests and the wall clock in production.  See
 ``core.pipeline.VirtualPool`` for the fake transport built on top.
+
+Spans beyond the four task kinds: on a wall-clock ``Trace`` the pipeline,
+the tiers and the engine also record what happens *inside* and *between*
+tasks.  Each is a ``TraceEvent`` marked ``span``, under a kind of its own,
+so no reader of the four task kinds sees it (``Trace.work_events``):
+
+  * ``<task kind>.<phase>`` — a step inside a transfer (``phase()``),
+    named after the task that ran it (``w[3]``, ``kv[7,3]``, ``sv[7,3]``);
+  * ``wait.<task kind>`` / ``wait.head`` — the main thread blocked on a
+    producer, named after it;
+  * ``queue.<task kind>`` — a task sat in the pool's queue, from submit
+    to start;
+  * ``engine.prefill`` / ``engine.decode`` — one admission's prefill
+    (named ``r<rid>``) or one decode step (named ``rows=<n>``).
+
+While JAX's profiler records, every span and every task also opens a
+``jax.profiler.TraceAnnotation`` of the same kind (name and bytes as its
+arguments), which puts it on the profiler's host plane beside the
+device's ops.  Nothing here imports JAX: the annotation is looked up
+only once the process has imported it.
 """
 from __future__ import annotations
 
+import collections
+import contextlib
 import json
+import sys
 import threading
 import time
 from dataclasses import dataclass, field
@@ -31,6 +54,18 @@ class TaskType(Enum):
     WEIGHT_LOAD = "weight_load"
     KV_LOAD = "kv_load"
     KV_SAVE = "kv_save"
+
+
+TASK_KINDS = frozenset(t.value for t in TaskType)
+
+# producers a main-thread wait is split by in ``Trace.report()["main"]``
+WAIT_KINDS = ("wait.weight_load", "wait.kv_load", "wait.kv_save",
+              "wait.head")
+
+# events a wall-clock Trace keeps: a ring, so a long-lived server's trace
+# stays bounded.  A traced benchmark window holds ~11k events; this keeps
+# several windows.
+TRACE_CAPACITY = 1 << 16
 
 
 # ---------------------------------------------------------------------------
@@ -67,6 +102,28 @@ class VirtualClock(Clock):
 
 
 WALL_CLOCK = WallClock()
+
+
+# ---------------------------------------------------------------------------
+# Profiler annotations
+# ---------------------------------------------------------------------------
+
+# what ``annotate``/``region``/``phase`` hand out when nothing is recorded
+_NULL = contextlib.nullcontext()
+
+
+def annotate(kind: str, name: str = "", nbytes: int = 0):
+    """A ``jax.profiler.TraceAnnotation`` named ``kind`` (with ``name``
+    and, when nonzero, ``bytes`` as its arguments) while JAX's profiler
+    records; otherwise a no-op context.  JAX is never imported from here:
+    a process that has not imported it cannot be profiling."""
+    jax = sys.modules.get("jax")
+    prof = getattr(jax, "profiler", None) if jax is not None else None
+    if prof is None or not prof.TraceAnnotation.is_enabled():
+        return _NULL
+    if nbytes:
+        return prof.TraceAnnotation(kind, name=name, bytes=int(nbytes))
+    return prof.TraceAnnotation(kind, name=name)
 
 
 @dataclass
@@ -115,13 +172,52 @@ class Task:
             self.t_end = clock.now()
             self.done.set()
 
-    def wait(self):
-        self.done.wait()
+    def wait(self, trace: Optional["Trace"] = None):
+        """Block until the task is done and return its result (or raise
+        its error).  With ``trace``, a wait that blocks is spanned there
+        as ``wait.<kind>`` under the task's name: the producer it waited
+        for (main thread)."""
+        if trace is not None and not self.done.is_set():
+            with trace.region(f"wait.{self.kind.value}", self.name):
+                self.done.wait()
+        else:
+            self.done.wait()
         if self.on_wait is not None:
             self.on_wait(self)
         if self.error is not None:
             raise self.error
         return self.result
+
+
+# the task the current pool thread runs, with the trace and thread name it
+# records under — set by ``ThreadPool`` around ``Task.run`` so code deep in
+# a transfer (the tiered stores) can open phases without holding a trace
+_current = threading.local()
+
+
+@contextlib.contextmanager
+def running(task: "Task", trace: "Trace", thread: str):
+    """Mark ``task`` as the one this thread runs, inside its profiler
+    annotation (pool threads)."""
+    _current.task = (task, trace, thread)
+    try:
+        with annotate(task.kind.value, task.name, task.nbytes):
+            yield
+    finally:
+        _current.task = None
+
+
+def phase(step: str, nbytes: int = 0):
+    """Span one step of the transfer task running on this thread, as kind
+    ``<task kind>.<step>`` under the task's name; ``nbytes`` is what the
+    step moved.  Outside a pool task (or on a virtual-clock trace) it
+    records nothing."""
+    cur = getattr(_current, "task", None)
+    if cur is None:
+        return _NULL
+    task, trace, thread = cur
+    return trace.region(f"{task.kind.value}.{step}", task.name, nbytes,
+                        thread)
 
 
 @dataclass
@@ -134,6 +230,9 @@ class TraceEvent:
     nbytes: int = 0
     extent: Optional[tuple] = None     # live (batch, len) of a KV payload
     stage: int = 0                     # pipeline-parallel stage (0 = single)
+    # a span (``Trace.record``/``region``/``phase``): a wait, a phase, a
+    # queue stretch or an engine step, never one of the four task kinds
+    span: bool = False
 
 
 def percentile(xs, q: float) -> float:
@@ -181,14 +280,72 @@ def _merged_busy(intervals) -> float:
     return busy
 
 
+def _attribute(labelled, lo: float, hi: float) -> Dict[str, float]:
+    """Seconds of [lo, hi] each label held, from (start, end, label)
+    intervals: where intervals overlap, the innermost (latest-started)
+    one holds the time, so a wait nested in a compute counts as the wait.
+    Time no interval covers is not returned."""
+    ivals = [(max(s, lo), min(t, hi), lab) for s, t, lab in labelled
+             if min(t, hi) > max(s, lo)]
+    cuts = sorted({x for s, t, _ in ivals for x in (s, t)})
+    out: Dict[str, float] = {}
+    ivals.sort()
+    k, open_ = 0, []
+    for a, b in zip(cuts, cuts[1:]):
+        while k < len(ivals) and ivals[k][0] <= a:
+            open_.append(ivals[k])
+            k += 1
+        open_ = [iv for iv in open_ if iv[1] > a]
+        if open_:
+            lab = max(open_, key=lambda iv: iv[0])[2]
+            out[lab] = out.get(lab, 0.0) + (b - a)
+    return out
+
+
+class _Region:
+    """One span being timed on a live trace (see ``Trace.region``)."""
+
+    __slots__ = ("trace", "kind", "name", "nbytes", "thread", "t0", "ann")
+
+    def __init__(self, trace, kind, name, nbytes, thread):
+        self.trace, self.kind, self.name = trace, kind, name
+        self.nbytes, self.thread = nbytes, thread
+
+    def __enter__(self):
+        self.ann = annotate(self.kind, self.name, self.nbytes)
+        self.ann.__enter__()
+        self.t0 = self.trace.clock.now()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = self.trace.clock.now()
+        self.ann.__exit__(*exc)
+        self.trace.record(self.kind, self.name, self.t0, t1, self.thread,
+                          self.nbytes)
+        return False
+
+
 class Trace:
     """Execution trace for the GPU-utilization analogue (Fig. 8) and the
     pipeline-overlap benchmarks.  Timestamps are relative to the clock's
-    value at construction (0 for a fresh VirtualClock)."""
+    value at construction (0 for a fresh VirtualClock).
+
+    A wall-clock (live) trace keeps its events in a ring of
+    ``TRACE_CAPACITY`` (the oldest go first, counted by ``dropped``); a
+    virtual-clock trace, simulated or loaded by ``from_json``, keeps them
+    all.  ``seq`` counts every event ever added, so ``events_since(mark)``
+    reads what arrived after an earlier ``seq`` without copying the ring.
+    Spans (``region``, ``record``, ``phase``) are kept only on a live
+    trace: a virtual-clock trace holds exactly the tasks, so its
+    recordings stay byte-stable."""
 
     def __init__(self, clock: Clock = WALL_CLOCK):
-        self._events: list[TraceEvent] = []
+        # spans beyond the task kinds are recorded on the wall clock only
+        self.live = not isinstance(clock, VirtualClock)
+        self._events = (collections.deque(maxlen=TRACE_CAPACITY)
+                        if self.live else [])
         self._lock = threading.Lock()
+        self.seq = 0
         self.clock = clock
         self.t0 = clock.now()
         # replayable context: schedulers/pools/engines stamp the knobs the
@@ -197,17 +354,60 @@ class Trace:
         # can rebuild the run without the model.  Serialized by to_json.
         self.meta: Dict[str, Any] = {}
 
-    def add(self, task: Task, thread: str):
+    def _append(self, ev: TraceEvent):
         with self._lock:
-            self._events.append(TraceEvent(task.kind.value, task.name,
-                                           task.t_start - self.t0,
-                                           task.t_end - self.t0, thread,
-                                           task.nbytes, task.extent,
-                                           task.stage))
+            self._events.append(ev)
+            self.seq += 1
+
+    def add(self, task: Task, thread: str):
+        self._append(TraceEvent(task.kind.value, task.name,
+                                task.t_start - self.t0,
+                                task.t_end - self.t0, thread,
+                                task.nbytes, task.extent, task.stage))
+
+    def record(self, kind: str, name: str, t_start: float, t_end: float,
+               thread: str = "main", nbytes: int = 0):
+        """Add a span of ``kind`` (never a task kind) between two
+        readings of this trace's clock; a no-op on a virtual clock."""
+        if self.live:
+            self._append(TraceEvent(kind, name, t_start - self.t0,
+                                    t_end - self.t0, thread, nbytes,
+                                    span=True))
+
+    def region(self, kind: str, name: str = "", nbytes: int = 0,
+               thread: str = "main"):
+        """Context manager spanning a block as one ``kind`` event (and a
+        profiler annotation while JAX's profiler records).  ``thread`` is
+        the executor the block runs on: the scheduler's waits and the
+        engine's steps run on ``"main"``.  A no-op on a virtual clock."""
+        if not self.live:
+            return _NULL
+        return _Region(self, kind, name, nbytes, thread)
 
     def events(self):
         with self._lock:
             return list(self._events)
+
+    @property
+    def dropped(self) -> int:
+        """Events the ring has let go (0 on a virtual-clock trace)."""
+        return self.seq - len(self._events)
+
+    def events_since(self, mark: int):
+        """Events added after ``seq`` read ``mark`` (those still in the
+        ring), oldest first, copying only them."""
+        with self._lock:
+            k = min(self.seq - mark, len(self._events))
+            if k <= 0:
+                return []
+            out = [ev for _, ev in zip(range(k), reversed(self._events))]
+        out.reverse()
+        return out
+
+    def work_events(self):
+        """Every event but the spans: the tasks, and any kind a hand-built
+        or simulated trace records as its work."""
+        return [e for e in self.events() if not e.span]
 
     # -- (de)serialization --------------------------------------------------
     def to_json(self) -> Dict[str, Any]:
@@ -224,6 +424,8 @@ class Trace:
             # fixtures recorded before pipeline parallelism stay byte-stable
             if e.stage:
                 ev["stage"] = e.stage
+            if e.span:
+                ev["span"] = True
             events.append(ev)
         return {"meta": dict(self.meta), "events": events}
 
@@ -242,15 +444,17 @@ class Trace:
         tr.meta = dict(d.get("meta", {}))
         for ev in d.get("events", []):
             ext = ev.get("extent")
-            tr._events.append(TraceEvent(
+            tr._append(TraceEvent(
                 ev["kind"], ev["name"], ev["t_start"], ev["t_end"],
                 ev.get("thread", ""), ev.get("nbytes", 0),
                 None if ext is None else tuple(ext),
-                ev.get("stage", 0)))
+                ev.get("stage", 0), ev.get("span", False)))
         return tr
 
     def span(self) -> float:
-        evs = self.events()
+        """First start to last end of the work events (spans excluded: a
+        queue span starts before its task, a step span wraps its tasks)."""
+        evs = self.work_events()
         if not evs:
             return 0.0
         return max(e.t_end for e in evs) - min(e.t_start for e in evs)
@@ -261,9 +465,31 @@ class Trace:
                             if e.kind == kind)
 
     def thread_busy(self, thread: str = "main") -> float:
-        """Merged-interval busy seconds on one executor thread."""
-        return _merged_busy((e.t_start, e.t_end) for e in self.events()
-                            if e.thread == thread)
+        """Merged-interval seconds one executor thread spent running
+        tasks (the spans of waits, phases and steps are not tasks)."""
+        return _merged_busy((e.t_start, e.t_end)
+                            for e in self.work_events() if e.thread == thread)
+
+    def main_split(self) -> Dict[str, Any]:
+        """Where the main thread's time went, from its first to its last
+        event: layer compute, each wait by the producer it waited for
+        (``WAIT_KINDS``), and the rest, host code (the scheduler, embeds,
+        sampling, admission bookkeeping).  Seconds and shares; the shares
+        sum to 1 over a non-empty window.  A wait nested inside a compute
+        (a MoE layer waiting for its experts) counts as the wait."""
+        keys = (TaskType.COMPUTE.value,) + WAIT_KINDS
+        evs = [e for e in self.events()
+               if e.thread == "main" and e.kind in keys]
+        lo = min((e.t_start for e in evs), default=0.0)
+        hi = max((e.t_end for e in evs), default=0.0)
+        secs = dict.fromkeys(keys, 0.0)
+        secs.update(_attribute(((e.t_start, e.t_end, e.kind) for e in evs),
+                               lo, hi))
+        window = hi - lo
+        secs["host"] = max(0.0, window - sum(secs.values()))
+        return {"window_s": window, "seconds": secs,
+                "share": {k: (v / window if window > 0 else 0.0)
+                          for k, v in secs.items()}}
 
     def busy_fraction(self, kind: str = "compute") -> float:
         """Fraction of the makespan the given task kind was executing —
@@ -282,9 +508,13 @@ class Trace:
                    if e.kind == kind and e.name.startswith(name_prefix))
 
     def report(self) -> Dict[str, Any]:
-        """Pipeline instrumentation (Fig. 8/9 analogue): per-task-type busy
-        time + counts, compute-thread utilization, and bubble accounting
-        (compute-thread idle time = pipeline stalls waiting on transfers)."""
+        """Pipeline instrumentation (Fig. 8/9 analogue): per-kind busy
+        time, counts and bytes (the four task kinds always; span kinds as
+        recorded), the main thread's time split into compute, waits by
+        producer and host code (``main_split``), and the events the ring
+        has let go (``dropped``: nonzero means the figures cover only the
+        newest ``TRACE_CAPACITY`` events).  Host-clock spans: the device's
+        own busy share comes from a profiler trace."""
         evs = self.events()
         span = self.span()
         per_kind = {}
@@ -308,20 +538,18 @@ class Trace:
                 # bandwidth feedback EWMAs per step
                 "bw_Bps": nbytes / busy if busy > 0 else 0.0,
             }
-        compute_busy = self.thread_busy("main")
         out = {
             "span_s": span,
             "per_kind": per_kind,
-            "compute_util": compute_busy / span if span > 0 else 0.0,
-            "bubble_s": max(0.0, span - compute_busy),
-            "bubble_frac": (max(0.0, span - compute_busy) / span
-                            if span > 0 else 0.0),
+            "main": self.main_split(),
+            "dropped": self.dropped,
         }
         # pipeline-parallel fill/drain accounting: when any event carries a
         # stage tag, each stage gets a bucket measuring how long it idles
         # before its first compute (fill — upstream stages haven't produced
         # an activation yet) and after its last (drain — downstream stages
         # are still flushing).  Single-stage traces skip the bucket.
+        evs = [e for e in evs if not e.span]
         if any(e.stage for e in evs):
             t_lo = min(e.t_start for e in evs)
             t_hi = max(e.t_end for e in evs)

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.offload import DiskStore
+from repro.core.tasks import phase
 
 DEFAULT_BLOCK = 8 * 2**20          # 8MB disk blocks (paper Appendix A)
 DEVICE_BLOCK = 32 * 2**20          # 32MB host->device blocks
@@ -223,26 +224,35 @@ class TieredWeightStore:
         t0 = time.perf_counter()
         man = self.manifests[key]
         self.load_counts[key] = self.load_counts.get(key, 0) + 1
-        if self.placement == "device":
-            buf = self.device.get(key)
-            views = split_views(np.asarray(buf), man)
-        elif self.placement == "host":
-            views = split_views(self.host.get(key), man)
-        else:
-            if self.cold_reads:
-                # evict page cache: measure real NVMe reads (paper regime)
-                self.disk.drop_cache(key)
-            host_buf = blockwise_disk_to_host(
-                self.disk, key, block_bytes=self.block_bytes,
-                n_threads=self.n_io_threads)
-            views = split_views(host_buf.view(np.uint8), man)
-        dev = {}
-        for name, arr in views.items():
-            dev[name] = jax.device_put(arr, self.target)
-        for a in dev.values():
-            a.block_until_ready()
-        self.sim_floor(man.total_bytes, t0)
-        return self._maybe_dequant(dev)
+        with phase("stage"):
+            if self.placement == "device":
+                buf = self.device.get(key)
+                views = split_views(np.asarray(buf), man)
+            elif self.placement == "host":
+                views = split_views(self.host.get(key), man)
+            else:
+                if self.cold_reads:
+                    # evict page cache: measure real NVMe reads (paper
+                    # regime)
+                    self.disk.drop_cache(key)
+                host_buf = blockwise_disk_to_host(
+                    self.disk, key, block_bytes=self.block_bytes,
+                    n_threads=self.n_io_threads)
+                views = split_views(host_buf.view(np.uint8), man)
+        # the link's phases: ``put`` carries the bytes, ``ready`` (and the
+        # simulated link's floor) moves the same ones
+        with phase("put", man.total_bytes):
+            dev = {}
+            for name, arr in views.items():
+                dev[name] = jax.device_put(arr, self.target)
+        with phase("ready"):
+            for a in dev.values():
+                a.block_until_ready()
+            self.sim_floor(man.total_bytes, t0)
+        if self.quant != "int4":
+            return dev
+        with phase("dequant"):
+            return self._maybe_dequant(dev)
 
     def _maybe_dequant(self, dev):
         """Dequantize INT4 ``#q``/``#s`` pairs after the (cheap, packed)

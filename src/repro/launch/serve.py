@@ -85,6 +85,7 @@ def main(argv=None):
             print(f"plan written to {args.plan_json}")
         return
 
+    from repro.core.tasks import TaskType
     from repro.serving import Request
     from repro.serving.spec import create_engine
 
@@ -105,10 +106,14 @@ def main(argv=None):
           f"stats={eng.stats}")
     if offloaded:
         rep = eng.pipeline_report()
-        busy = {k: f"{v['busy_s']:.2f}s" for k, v in rep["per_kind"].items()}
+        busy = {t.value: f"{rep['per_kind'][t.value]['busy_s']:.2f}s"
+                for t in TaskType}
+        # host-clock spans: where the main thread's time went, not the
+        # device's utilization (that needs a profiler trace)
+        split = " ".join(f"{k}={v:.2f}"
+                         for k, v in rep["main"]["share"].items())
         print(f"pipeline[{plan.pipeline}] depth={eng.sched.depth} "
-              f"compute_util={rep['compute_util']:.2f} "
-              f"bubble_frac={rep['bubble_frac']:.2f} busy={busy}")
+              f"main thread: {split} busy={busy}")
     eng.shutdown()
 
 

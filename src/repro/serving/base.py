@@ -42,7 +42,7 @@ import numpy as np
 from repro.core.kvstore import PhasedKVExtents
 from repro.core.offload import HostStore
 from repro.core.pipeline import ThreadPool
-from repro.core.tasks import Task, TaskType
+from repro.core.tasks import Task, TaskType, Trace
 
 
 @dataclass
@@ -58,16 +58,17 @@ class Request:
     # filled by the engine
     out: List[int] = field(default_factory=list)
     t_submit: float = 0.0
-    t_first: float = 0.0
     t_done: float = 0.0
     # per-request latency accounting (both engines, same fields, so TTFT
     # parity is comparable engine-to-engine): ``t_arrive`` is the
     # request's scheduled arrival — a workload driver sets it BEFORE
     # submit to charge queue wait to the request; submit defaults it to
-    # t_submit.  ``t_first_token`` mirrors t_first (kept separate so the
-    # legacy field keeps its exact historical meaning); ``t_tokens``
-    # records one timestamp per emitted token for TBT percentiles.
+    # t_submit.  ``t_admit`` is when it first took a slot (its queue wait
+    # ends, its prefill starts); ``t_first_token`` when its first token
+    # reached the host; ``t_tokens`` records one timestamp per emitted
+    # token for TBT percentiles.
     t_arrive: float = 0.0
+    t_admit: float = 0.0
     t_first_token: float = 0.0
     t_tokens: List[float] = field(default_factory=list)
     # preemption state: >= 0 means this request's KV rows are spilled to
@@ -97,6 +98,10 @@ class SlotEngineBase(PhasedKVExtents):
     least-recently-written namespaces are evicted first, except those of
     currently-parked (preempted) requests, whose rows are still needed to
     resume."""
+
+    # where a blocking wait on a slot save is spanned (``Task.wait``): the
+    # offloaded engine's pipeline trace; None records nothing
+    trace: Optional[Trace] = None
 
     def __init__(self, cfg, *, b_max: int = 4, max_len: int = 256,
                  kv_pool: Optional[ThreadPool] = None, spill_cap: int = 32):
@@ -228,7 +233,7 @@ class SlotEngineBase(PhasedKVExtents):
         occupant before its rows are reused."""
         t = self._slot_saves.pop(slot, None)
         if t is not None:
-            t.wait()
+            t.wait(self.trace)
 
     def _admit(self):
         while self.queue:
@@ -276,6 +281,7 @@ class SlotEngineBase(PhasedKVExtents):
             return False
         self.queue.pop(0)
         self._sync_slot(slot)
+        req.t_admit = time.perf_counter()
         if state == self.CHUNK_STARTED:
             # reserve the slot; chunk steps run inside _decode_step and
             # the first token lands via _finish_prefill at completion
@@ -293,7 +299,6 @@ class SlotEngineBase(PhasedKVExtents):
         self.stats["prefills"] += 1
         req.out.append(tok)
         now = time.perf_counter()
-        req.t_first = now
         req.t_first_token = now
         req.t_tokens.append(now)
         self.slots[slot] = req
@@ -379,7 +384,8 @@ class SlotEngineBase(PhasedKVExtents):
             self._spill_lru.pop(victim)
             t = self._ns_saves.pop(victim, None)
             if t is not None:
-                t.wait()        # never delete under an in-flight write
+                # never delete under an in-flight write
+                t.wait(self.trace)
             self._delete_spill_keys(victim)
             self.stats["spill_evictions"] += 1
 
@@ -388,7 +394,7 @@ class SlotEngineBase(PhasedKVExtents):
         self._spill_lru.pop(ns, None)
         t = self._ns_saves.pop(ns, None)
         if t is not None:
-            t.wait()
+            t.wait(self.trace)
         self._delete_spill_keys(ns)
 
     def _delete_spill_keys(self, ns: str):

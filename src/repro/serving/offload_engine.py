@@ -82,7 +82,7 @@ from repro.core.draft import accepted_tokens
 from repro.core.kvstore import TieredKVStore, kv_roundtrip_traceable
 from repro.core.offload import DeviceStore, DiskStore
 from repro.core.pipeline import PipelineScheduler, StagedScheduler, ThreadPool
-from repro.core.tasks import Task, TaskType, Trace, _merged_busy
+from repro.core.tasks import Task, TaskType, Trace, _merged_busy, phase
 from repro.core.transfer import TieredWeightStore, int4_roundtrip
 from repro.launch.mesh import stage_devices
 from repro.models import Dist, build_model
@@ -125,6 +125,22 @@ class _Unit:
     moe: bool = False
     router: Any = None                     # device (d, E) gate weights
     expert_keys: List[str] = field(default_factory=list)
+
+
+def _fetch(rows: Dict[str, Any], index=None) -> Dict[str, np.ndarray]:
+    """Device cache rows -> host, as a KV_SAVE's ``fetch`` phase
+    carrying the bytes copied; ``index`` (a slot, or a slice of the live
+    rows, on the leading axis) picks what crosses, None the whole leaves.
+    The bytes are read from the shapes, so the phase also spans the
+    device-side pick."""
+    def nbytes(a):
+        n = a.shape[0]
+        return a.nbytes // n * (n if index is None
+                                else np.arange(n)[index].size)
+
+    with phase("fetch", sum(nbytes(a) for a in rows.values())):
+        return {name: np.asarray(a if index is None else a[index])
+                for name, a in rows.items()}
 
 
 def quant_roundtrip_params(cfg: ModelConfig, params):
@@ -792,35 +808,45 @@ class OffloadedServingEngine(SlotEngineBase):
         kv_mode='int4').  Transfer-pool thread; the scheduler guarantees
         the save lands before iteration i+1's KV_LOAD of the same
         unit."""
-        phase, payload, meta = new_kv
-        if phase == "prefill":
+        kind, payload, meta = new_kv
+        # phases: ``sync`` waits for the layer's program to produce the
+        # rows (the copy would block on it anyway), ``fetch`` copies them
+        # device->host and carries the bytes, ``scatter`` writes the host
+        # tier
+        with phase("sync"):
+            jax.block_until_ready((payload, meta[:2]) if kind == "mixed"
+                                  else payload)
+        if kind == "prefill":
             slot = meta
-            self.kvstore.save_prefill(
-                j, slot, {n: np.asarray(l[0]) for n, l in payload.items()})
-        elif phase == "mixed":
+            rows = _fetch(payload, 0)
+            with phase("scatter"):
+                self.kvstore.save_prefill(j, slot, rows)
+        elif kind == "mixed":
             # a step carrying a prefill chunk: the decode batch's rows
             # (when a decode rode along) plus the chunk's per-position
             # append — the same quantize-once ``save_decode`` row path,
             # so the stored bytes match a monolithic prefill's exactly
+            k_ck, v_ck, slot, c0 = meta
+            rows_d = None
             if payload is not None:
                 rows_d, (active, pos, live_b) = payload
-                rows = {n: np.asarray(l[:live_b])
-                        for n, l in rows_d.items()}
-                self.kvstore.save_decode(j, rows, active, pos)
-            k_ck, v_ck, slot, c0 = meta
-            rows = {}
-            for name, arr in (("k", k_ck), ("v", v_ck)):
-                a = np.asarray(arr)                     # (1, c, *feat)
-                buf = np.zeros((slot + 1,) + a.shape[1:], a.dtype)
-                buf[slot] = a[0]
-                rows[name] = buf
-            self.kvstore.save_decode(
-                j, rows, [slot], np.full(slot + 1, c0, np.int32))
+                rows_d = _fetch(rows_d, slice(None, live_b))
+            ck = _fetch({"k": k_ck, "v": v_ck})             # (1, c, *feat)
+            with phase("scatter"):
+                if rows_d is not None:
+                    self.kvstore.save_decode(j, rows_d, active, pos)
+                rows = {}
+                for name, a in ck.items():
+                    buf = np.zeros((slot + 1,) + a.shape[1:], a.dtype)
+                    buf[slot] = a[0]
+                    rows[name] = buf
+                self.kvstore.save_decode(
+                    j, rows, [slot], np.full(slot + 1, c0, np.int32))
         else:
             active, pos, live_b = meta
-            rows = {n: np.asarray(l[:live_b])
-                    for n, l in payload.items()}
-            self.kvstore.save_decode(j, rows, active, pos)
+            rows = _fetch(payload, slice(None, live_b))
+            with phase("scatter"):
+                self.kvstore.save_decode(j, rows, active, pos)
 
     def compute(self, i: int, j: int, x, weights, kv):
         """COMPUTE body (main thread): one unit's jitted forward.  MoE
@@ -898,7 +924,7 @@ class OffloadedServingEngine(SlotEngineBase):
             tasks.append(t)
         shared_term = shared(weights, xn)         # overlaps expert loads
         ids_u = np.searchsorted(union, ids)       # order-preserving remap
-        loaded = [t.wait() for t in tasks]        # device arrays (deq'd)
+        loaded = [t.wait(self.trace) for t in tasks]   # device arrays
         wga = jnp.stack([we["w_gate"] for we in loaded])
         wup = jnp.stack([we["w_up"] for we in loaded])
         wdn = jnp.stack([we["w_down"] for we in loaded])
@@ -931,7 +957,9 @@ class OffloadedServingEngine(SlotEngineBase):
         else:
             tok = self._head(self.resident["embed"],
                              self.resident["final_norm"], x)
-        return np.asarray(tok)
+        # the step's whole device chain ends in the head's tokens
+        with self.trace.region("wait.head", "head"):
+            return np.asarray(tok)
 
     # ---- SlotEngineBase compute hooks ---------------------------------------
     def _begin_chunked_prefill(self, slot: int, req: Request) -> int:
@@ -1004,24 +1032,25 @@ class OffloadedServingEngine(SlotEngineBase):
         KV preload issued at the tail of this call captured the prefill
         phase (value None) and is dropped — the next decode step reloads
         fresh; its weight preload stays valid (weights are immutable)."""
-        self._phase = "prefill"
-        self._slot = slot
-        s = len(req.prompt)
-        positions = jnp.arange(s)
-        self._angles = T._angles(self.cfg, positions)
-        x0 = self._embed(self.resident["embed"],
-                         jnp.asarray(req.prompt)[None], "prefill")
-        toks = self.sched.generate(self, lambda i: x0, 1)
-        self.sched.drop_kv_preloads()
-        if self.draft is not None:
-            # admit the prompt into the draft's device cache too (the
-            # draft is slaved to the same slot/pos state)
-            self.draft.prefill_slot(slot, req.prompt)
+        with self.trace.region("engine.prefill", f"r{req.rid}"):
+            self._phase = "prefill"
+            self._slot = slot
+            s = len(req.prompt)
+            positions = jnp.arange(s)
+            self._angles = T._angles(self.cfg, positions)
+            x0 = self._embed(self.resident["embed"],
+                             jnp.asarray(req.prompt)[None], "prefill")
+            toks = self.sched.generate(self, lambda i: x0, 1)
+            self.sched.drop_kv_preloads()
+            if self.draft is not None:
+                # admit the prompt into the draft's device cache too (the
+                # draft is slaved to the same slot/pos state)
+                self.draft.prefill_slot(slot, req.prompt)
         # skip the prefill's trace window for the bandwidth feedback: a
         # full-prompt forward is far costlier per layer than a decode
         # step, and folding it into the compute EWMA would resolve the
         # window too shallow exactly while request load is ramping
-        self._trace_mark = len(self.trace.events())
+        self._trace_mark = self.trace.seq
         return int(toks[-1][0])
 
     def _observe_trace(self):
@@ -1033,8 +1062,8 @@ class OffloadedServingEngine(SlotEngineBase):
         observe = getattr(self.preload_policy, "observe", None)
         if observe is None:
             return
-        evs = self.trace.events()
-        new, self._trace_mark = evs[self._trace_mark:], len(evs)
+        new = self.trace.events_since(self._trace_mark)
+        self._trace_mark = self.trace.seq
         if not new:
             return
         xfer = [e for e in new if e.kind in ("weight_load", "kv_load")]
@@ -1113,11 +1142,17 @@ class OffloadedServingEngine(SlotEngineBase):
         return super()._emitted_tokens(active, nt)
 
     def _decode_active(self, active: List[int]) -> np.ndarray:
-        """One batched decode step through the pipeline (main thread).
-        With a warm scheduler the step's first weight/KV loads were
-        pre-submitted during the previous step's tail compute.  With a
-        draft attached the step is a draft-then-verify pass emitting up
-        to spec_k + 1 tokens per slot (``_emitted_tokens``)."""
+        """One batched decode step through the pipeline (main thread),
+        spanned as ``engine.decode`` with its row count.  With a warm
+        scheduler the step's first weight/KV loads were pre-submitted
+        during the previous step's tail compute.  With a draft attached
+        the step is a draft-then-verify pass emitting up to spec_k + 1
+        tokens per slot (``_emitted_tokens``)."""
+        with self.trace.region("engine.decode", f"rows={len(active)}"):
+            return self._decode_rows(active)
+
+    def _decode_rows(self, active: List[int]) -> np.ndarray:
+        """The body of ``_decode_active``, inside its span."""
         self._spec_emitted = None
         self._spec_s = 1
         if self._chunk is not None:

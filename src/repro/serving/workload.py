@@ -276,7 +276,7 @@ class TrafficSim:
 
         def emit(ev_kind, name, dt):
             nonlocal t, sweeps
-            tr._events.append(TraceEvent(ev_kind, name, t, t + dt, "main"))
+            tr._append(TraceEvent(ev_kind, name, t, t + dt, "main"))
             t += dt
             sweeps += 1
             clock.advance_to(t)

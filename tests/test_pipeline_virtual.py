@@ -10,7 +10,7 @@ import pytest
 
 from fake_model import (COSTS, DRAFT_NAME, FakeMoEModel, run_virtual,
                         run_virtual_moe, run_virtual_spec)
-from repro.core.tasks import TaskType
+from repro.core.tasks import WAIT_KINDS, TaskType
 
 
 def _by_name(trace):
@@ -603,8 +603,14 @@ def test_trace_report_accounts_busy_time():
     assert abs(rep["span_s"] - expect) < 1e-9
     assert abs(rep["per_kind"]["compute"]["busy_s"]
                - model.n * COSTS[TaskType.COMPUTE]) < 1e-9
-    assert rep["bubble_s"] > 0
-    assert abs(rep["compute_util"] + rep["bubble_frac"] - 1.0) < 1e-9
+    # a virtual trace holds tasks only: the main thread's window splits
+    # into compute and the rest (no wait spans), summing to the whole
+    main = rep["main"]
+    assert main["seconds"]["host"] > 0
+    assert all(main["seconds"][k] == 0.0 for k in WAIT_KINDS)
+    assert abs(sum(main["share"].values()) - 1.0) < 1e-9
+    assert abs(main["seconds"]["compute"]
+               - model.n * COSTS[TaskType.COMPUTE]) < 1e-9
 
 
 def test_drop_kv_preloads_reraises_a_failed_preload():

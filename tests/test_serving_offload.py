@@ -622,8 +622,14 @@ def test_offload_pipeline_report_populated():
     assert rep["per_kind"]["weight_load"]["bytes"] > 0
     assert rep["per_kind"]["kv_load"]["count"] > 0
     assert rep["per_kind"]["kv_save"]["count"] > 0
-    assert 0 < rep["compute_util"] <= 1
-    assert abs(rep["compute_util"] + rep["bubble_frac"] - 1.0) < 1e-9
+    # the main thread's window: compute, waits by producer and host code
+    # sum to the whole.  Every step ends waiting for its head's tokens;
+    # whether a load is still running when the thread reaches it depends
+    # on timing (tests/test_trace_spans.py forces those waits)
+    main = rep["main"]
+    assert 0 < main["share"]["compute"] <= 1
+    assert abs(sum(main["share"].values()) - 1.0) < 1e-9
+    assert main["seconds"]["wait.head"] > 0
 
 
 # ---------------------------------------------------------------------------

@@ -11,12 +11,14 @@ import json
 
 import pytest
 
-from repro.core.tasks import Task, TaskType, Trace, TraceEvent, VirtualClock
+from repro.core.tasks import (WAIT_KINDS, Task, TaskType, Trace, TraceEvent,
+                              VirtualClock)
 
 
 def _trace(events=()):
     tr = Trace(clock=VirtualClock())
-    tr._events.extend(events)
+    for e in events:
+        tr._append(e)
     return tr
 
 
@@ -33,9 +35,11 @@ def _ev(kind="compute", name="c[0,0]", t0=0.0, t1=1.0, thread="main",
 def test_empty_trace_report_is_all_zero():
     rep = _trace().report()
     assert rep["span_s"] == 0.0
-    assert rep["compute_util"] == 0.0
-    assert rep["bubble_s"] == 0.0
-    assert rep["bubble_frac"] == 0.0
+    main = rep["main"]
+    assert main["window_s"] == 0.0
+    assert set(main["seconds"]) == {"compute", *WAIT_KINDS, "host"}
+    assert all(v == 0.0 for v in main["seconds"].values())
+    assert all(v == 0.0 for v in main["share"].values())
     for kind in (t.value for t in TaskType):
         pk = rep["per_kind"][kind]
         assert pk == {"busy_s": 0.0, "count": 0, "busy_frac": 0.0,
@@ -62,7 +66,7 @@ def test_zero_duration_events_no_division_error():
     assert pk["bytes"] == 4096
     assert pk["bw_Bps"] == 0.0              # the divide-by-zero guard
     assert rep["span_s"] == 0.0             # single instant: no span
-    assert rep["compute_util"] == 0.0
+    assert rep["main"]["share"]["compute"] == 0.0
     assert tr.bytes_moved("weight_load") == 4096
 
 
