@@ -33,6 +33,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import Dist, build_model
+from repro.models import transformer as T
 
 __all__ = ["ResidentDraft", "accept_length", "accepted_tokens"]
 
@@ -79,7 +80,8 @@ class ResidentDraft:
         self.max_len = max_len
         self.dist = Dist.local()
         self.model = build_model(cfg)
-        self.params = self.model.init(jax.random.PRNGKey(seed), jnp.float32)
+        self.params = T.served_params(
+            cfg, self.model.init(jax.random.PRNGKey(seed), jnp.float32))
         self.caches = self.model.init_cache(b_max, max_len)
         m, dist = self.model, self.dist
 

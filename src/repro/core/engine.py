@@ -37,6 +37,7 @@ from repro.core.offload import DeviceStore, DiskStore, HostStore
 from repro.core.pipeline import PipelineScheduler, ThreadPool
 from repro.core.tasks import Trace
 from repro.core.transfer import Manifest, TieredWeightStore
+from repro.models import transformer as T
 from repro.models.attention import (decode_attention, ref_attention,
                                     spec_decode_attention)
 from repro.models.common import rms_norm, silu
@@ -158,7 +159,9 @@ class PipelinedLM(PhasedKVExtents):
     placement: "device" | "host" | "disk" — where the merged unit weights
     live (paper's Weight-on GPU/CPU/Disk).  cache_on: "host" | "device".
     pipeline: "performance" | "memory" | "sequential".
-    quant: None | "int4".
+    quant: None | "int4".  Unit weights are drawn at f32 and held at the
+    configuration's dtype (``transformer.served_layer``), like every
+    serving engine's.
     depth: performance-pipeline preload window (layers in flight beyond
     the computing one; 1 = the paper's two-resident-layer invariant).
     """
@@ -279,7 +282,7 @@ class PipelinedLM(PhasedKVExtents):
                 else:
                     qt[name] = arr
             t = qt
-        return t
+        return T.served_layer(cfg, t, self.quant)
 
     def _put_tier(self, key: str, tensors: dict):
         self.weights.put(key, tensors)
@@ -304,7 +307,8 @@ class PipelinedLM(PhasedKVExtents):
                 d = cfg.d_model
                 self.device.put(f"wg[{l}]",
                                 (rng.standard_normal((d, moe.num_experts))
-                                 / math.sqrt(d)).astype(np.float32))
+                                 / math.sqrt(d)).astype(np.float32)
+                                .astype(T.served_dtype(cfg, "wg")))
                 for e in range(moe.num_experts):
                     self._put_tier(f"exp[{l}][{e}]",
                                    self._unit_tensors("mlp", rng))

@@ -105,6 +105,54 @@ def init_params(cfg: ModelConfig, key, dtype=jnp.bfloat16):
     return params
 
 
+# SSM scalars kept at f32 whatever the stated precision (``param_struct``)
+F32_NAMES = ("A_log", "dt_bias", "D")
+
+
+def served_weight_dtype(cfg: ModelConfig, quant: Optional[str] = None) -> str:
+    """The dtype a serving engine holds and streams a decoder layer's
+    matrices at: the configuration's stated precision, or f32 for units
+    streamed as INT4 (``quant="int4"``), which quantize from the f32 draw
+    and dequantize back to f32."""
+    return "float32" if quant == "int4" else jnp.dtype(cfg.dtype).name
+
+
+def served_dtype(cfg: ModelConfig, name: str, quant: Optional[str] = None):
+    """The dtype of decoder layer tensor ``name`` in a serving engine: a
+    MoE router at ``router_dtype``, packed INT4 leaves, their scales and
+    the SSM scalars as drawn, every other tensor (experts included) at
+    ``served_weight_dtype``."""
+    if name == "wg" and cfg.moe is not None:
+        return jnp.dtype(cfg.moe.router_dtype)
+    if name.endswith("#q"):
+        return jnp.dtype(jnp.uint8)
+    if name.endswith("#s") or name in F32_NAMES:
+        return jnp.dtype(jnp.float32)
+    return jnp.dtype(served_weight_dtype(cfg, quant))
+
+
+def served_layer(cfg: ModelConfig, tensors: dict,
+                 quant: Optional[str] = None) -> dict:
+    """One layer's tensors (name -> device or host array, drawn at f32)
+    rounded once, to nearest even, to their ``served_dtype``.  The one
+    rule every serving engine applies to the weights it draws, so that
+    their tokens agree; a tensor already at its dtype is returned as is."""
+    out = {}
+    for name, a in tensors.items():
+        dt = served_dtype(cfg, name, quant)
+        out[name] = a if a.dtype == dt else a.astype(dt)
+    return out
+
+
+def served_params(cfg: ModelConfig, params):
+    """``init_params`` output as a resident serving engine holds it: the
+    ``pat``/``rem`` layer tensors through ``served_layer``; the embedding
+    and the final norm as drawn."""
+    return {**params,
+            "pat": tuple(served_layer(cfg, t) for t in params["pat"]),
+            "rem": tuple(served_layer(cfg, t) for t in params["rem"])}
+
+
 def map_params_tree(cfg: ModelConfig, fn):
     """Build a pytree with the exact structure of ``init_params`` output,
     with leaf = fn(name, ParamDef, stacked: bool)."""
@@ -129,15 +177,13 @@ def map_params_tree(cfg: ModelConfig, fn):
 
 def param_struct(cfg: ModelConfig, dtype=jnp.bfloat16):
     """ShapeDtypeStruct tree matching init_params (fp32 SSM scalars)."""
-    f32_names = ("A_log", "dt_bias", "D")
-
     def fn(name, pd, stacked):
         stack = (cfg.num_encoder_layers if False else
                  (cfg.num_periods if stacked else 0))
         shape = ((stack,) + pd.shape) if stack else pd.shape
         if name.endswith("#q"):
             dt = jnp.uint8
-        elif name.endswith("#s") or name in f32_names:
+        elif name.endswith("#s") or name in F32_NAMES:
             dt = jnp.float32
         else:
             dt = dtype
@@ -148,7 +194,7 @@ def param_struct(cfg: ModelConfig, dtype=jnp.bfloat16):
         # encoder stacks over num_encoder_layers, not num_periods
         def fn_enc(name, pd, stacked):
             shape = ((cfg.num_encoder_layers,) + pd.shape) if stacked else pd.shape
-            dt = jnp.float32 if name in f32_names else dtype
+            dt = jnp.float32 if name in F32_NAMES else dtype
             return jax.ShapeDtypeStruct(shape, dt)
         tabs = model_tables(cfg)
         tree["enc"]["pat"] = tuple(

@@ -28,6 +28,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.core.pipeline import ThreadPool
 from repro.models import Dist, build_model
+from repro.models import transformer as T
 from repro.serving.base import Request, SlotEngineBase
 from repro.serving.spec import ResolvedPlan
 
@@ -52,11 +53,15 @@ class ServingEngine(SlotEngineBase):
         self.params = self.model.init(jax.random.PRNGKey(seed), jnp.float32)
         if self.plan is not None and self.plan.moe_quant:
             # INT4-resident MoE: pack the routed expert stacks once at
-            # load; decode unpacks them through the fused-int4 path
+            # load, from the f32 draw; decode unpacks them through the
+            # fused-int4 path
             from repro.serving.spec import quant_policy_for
             self.params = quant_policy_for(
                 self.plan.quant, self.plan.kv_mode,
                 self.plan.moe_quant).prepare_moe_params(self.params)
+        # layer tensors at the configuration's dtype, as every serving
+        # engine holds them
+        self.params = T.served_params(cfg, self.params)
         self.caches = self.model.init_cache(
             b_max, max_len, cfg.encoder_seq_len if cfg.enc_dec else None)
         self._jit()

@@ -55,8 +55,9 @@ the Trace each step (see ``_observe_trace``).
 
 Numerics are *identical* to the resident engine: both run the same
 ``models.layers`` / ``models.moe`` functions on params from the same
-``model.init`` seed, so decoded tokens match exactly (asserted in
-tests/test_serving_offload.py).
+``model.init`` seed, drawn at f32 and rounded once to the configuration's
+``dtype`` by the same rule (``transformer.served_dtype``), so decoded
+tokens match exactly (asserted in tests/test_serving_offload.py).
 
 Pipeline modes (pick with ``pipeline=``):
   * "performance" — preload layer j+1's weights during layer j's compute;
@@ -147,9 +148,11 @@ def quant_roundtrip_params(cfg: ModelConfig, params):
     """INT4 quantize->dequantize exactly the leaves the offloaded engine
     streams as INT4 — per-layer 2-D projections and per-expert MoE slices
     — leaving embeddings/final-norm/routers (device-resident, never
-    streamed) untouched.  Feeding the result to a resident
-    ``ServingEngine`` builds the reference the INT4 offloaded engine must
-    match token-for-token (tests/test_serving_offload.py)."""
+    streamed) untouched.  ``params`` is the f32 draw
+    (``model.init(key, jnp.float32)``), which INT4 units quantize from.
+    Feeding the result to a resident ``ServingEngine`` builds the
+    reference the INT4 offloaded engine must match token-for-token
+    (tests/test_serving_offload.py)."""
     def do_tab(tab, spec, stacked):
         out = {}
         for name, leaf in tab.items():
@@ -485,7 +488,7 @@ class OffloadedServingEngine(SlotEngineBase):
         self._chunk_tok = 0               # first token, set at final chunk
         # bytes staged device-side into compact MoE combine stacks — the
         # |union|-proportionality proof (tests assert it equals loaded
-        # experts x per-expert fp32 bytes, strictly below the full bank)
+        # experts x per-expert served bytes, strictly below the full bank)
         self.stats["moe_stack_bytes"] = 0
         self.stats["preload_depth"] = depth
         self.stats["depth_resizes"] = 0
@@ -521,6 +524,7 @@ class OffloadedServingEngine(SlotEngineBase):
         self.trace.meta.update(
             arch=plan.arch, b_max=plan.b_max, max_len=plan.max_len,
             sim_bw=plan.sim_bw, quant=plan.quant,
+            weight_dtype=T.served_weight_dtype(cfg, plan.quant),
             kv_mode=plan.kv_mode or "fp32")
         self._jit_units()
         # speculative decoding: a device-resident draft proposes spec_k
@@ -581,8 +585,10 @@ class OffloadedServingEngine(SlotEngineBase):
         return self.quant_policy.prepare_unit(tensors)
 
     def _split_params(self, params):
-        """Embeddings/final norm stay on device (small, needed every step);
-        each layer's params merge into one tiered buffer.  MoE layers
+        """Embeddings/final norm stay on device (small, needed every step)
+        as drawn; each layer's params, at their served dtypes
+        (``transformer.served_dtype``: the configuration's ``dtype``, f32
+        under INT4 streaming), merge into one tiered buffer.  MoE layers
         split further: the router stays on device (tiny; needed before
         any expert prefetch), each expert becomes its own tiered buffer so
         decode can load just the routed union (paper Appendix C.4).
@@ -594,16 +600,23 @@ class OffloadedServingEngine(SlotEngineBase):
                                          self.stage_devices[0]),
         }
         cfg = self.cfg
+
+        def host(name, a):
+            # rounded to the served dtype on the device, one tensor of
+            # one layer at a time (beside the drawn f32 tree), so the
+            # device-to-host copy moves the served bytes
+            return np.asarray(a.astype(T.served_dtype(cfg, name, self.quant)))
+
         for p in range(cfg.num_periods):
             for q, spec in enumerate(cfg.pattern):
                 key = f"u[{p}][{q}]"
-                tensors = {name: np.asarray(leaf[p])
+                tensors = {name: host(name, leaf[p])
                            for name, leaf in params["pat"][q].items()}
                 self.units.append(self._make_unit("pat", p, q, spec, key,
                                                   tensors))
         for q, spec in enumerate(cfg.remainder):
             key = f"rem[{q}]"
-            tensors = {name: np.asarray(leaf)
+            tensors = {name: host(name, leaf)
                        for name, leaf in params["rem"][q].items()}
             self.units.append(self._make_unit("rem", 0, q, spec, key,
                                               tensors))

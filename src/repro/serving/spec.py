@@ -863,11 +863,14 @@ class ResolvedPlan:
         return _registry_config(self.arch, self.scaled, self.cfg)
 
     def summary(self) -> str:
+        from repro.models.transformer import served_weight_dtype
+        weights = served_weight_dtype(self.model_config(), self.quant)
         return (f"{self.arch}{'(scaled)' if self.scaled else ''} "
                 f"engine={self.engine} placement={self.placement} "
                 f"pipeline={self.pipeline} warm={self.warm} "
                 f"depth={self.depth}({self.depth_policy}) "
-                f"quant={self.quant or 'fp32'} "
+                f"quant={self.quant or 'none'} "
+                f"weights={weights} "
                 f"kv={self.kv_mode or 'n/a'} b_max={self.b_max} "
                 f"max_len={self.max_len}"
                 + (f" draft={self.draft_arch} spec_k={self.spec_k}"

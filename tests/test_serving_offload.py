@@ -2,7 +2,10 @@
 through the PIPO pipeline) must match the resident ServingEngine token for
 token — warm or cold pipeline, FP16 or INT4 streaming, dense or MoE — and
 slot offload -> restore -> resume must be lossless."""
+import dataclasses
+
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -28,6 +31,12 @@ def _offload_spec(cfg, **kw):
 
 def _moe_cfg():
     return scaled_down(get_config("llama4-scout-17b-a16e"))
+
+
+def _f32_draw(eng):
+    """The f32 parameters an engine drew (seed 0) before rounding them to
+    the served dtype: what INT4 units quantize from."""
+    return eng.model.init(jax.random.PRNGKey(0), jnp.float32)
 
 
 def _prompts(cfg, n=4, rng_seed=0):
@@ -127,7 +136,7 @@ def test_offload_int4_decode_parity():
     resident path'), and the streamed bytes actually shrink."""
     cfg = _cfg()
     ref = ServingEngine(cfg, b_max=2, max_len=64)
-    ref.params = quant_roundtrip_params(cfg, ref.params)
+    ref.params = quant_roundtrip_params(cfg, _f32_draw(ref))
     ref_tokens = _serve(ref, _prompts(cfg))
 
     eng = OffloadedServingEngine(cfg, b_max=2, max_len=64,
@@ -150,7 +159,7 @@ def test_offload_int4_depth_parity(depth):
     reference token for token."""
     cfg = _cfg()
     ref = ServingEngine(cfg, b_max=2, max_len=64)
-    ref.params = quant_roundtrip_params(cfg, ref.params)
+    ref.params = quant_roundtrip_params(cfg, _f32_draw(ref))
     ref_tokens = _serve(ref, _prompts(cfg))
     eng = _offload_spec(cfg, b_max=2, max_len=64, pipeline="performance",
                         quant="int4", depth=depth)
@@ -227,7 +236,7 @@ def kv_int4_roundtrip_tokens():
     from repro.serving import KVRoundtripServingEngine
     cfg = _cfg()
     ref = KVRoundtripServingEngine(cfg, b_max=2, max_len=64)
-    ref.params = quant_roundtrip_params(cfg, ref.params)
+    ref.params = quant_roundtrip_params(cfg, _f32_draw(ref))
     return _serve(ref, _prompts(cfg))
 
 
@@ -310,10 +319,10 @@ def test_moe_quant_resident_parity():
     """moe_quant='int4' — the resident engine's routed expert stacks
     packed once at load, unpacked per step through the fused-int4 path —
     decodes token-identical to a resident engine holding the SAME
-    roundtripped stacks, and the resident expert bytes shrink >6x."""
-    import jax.numpy as jnp
+    roundtripped stacks, and the resident expert bytes shrink >6x from
+    an f32 bank (the configuration states f32 weights)."""
     from repro.quant.int4 import dequantize_int4_stack
-    cfg = _moe_cfg()
+    cfg = dataclasses.replace(_moe_cfg(), dtype="float32")
     prompts = _prompts(cfg, 3)
     eng = create_engine(EngineSpec(arch=cfg.name, cfg=cfg, offload=False,
                                    b_max=2, max_len=48, moe_quant="int4"))
@@ -413,7 +422,7 @@ def test_offload_moe_loads_routed_union_only():
 
 def test_offload_moe_compact_combine_stacks_union_bytes():
     """The combine boundary is |union|-proportional too (the PR-2 gap):
-    the compact combine stacks exactly the loaded experts — one fp32
+    the compact combine stacks exactly the loaded experts — one
     slot per expert WEIGHT_LOAD — never a zero-padded full bank, so
     total stacked bytes sit strictly below the bank-sized staging the
     padded combine used to do every MoE step."""
@@ -428,13 +437,14 @@ def test_offload_moe_compact_combine_stacks_union_bytes():
     total_loads = sum(eng.weights.load_counts.get(k, 0)
                       for k in expert_keys)
     d, f = cfg.d_model, m.expert_d_ff
-    per_expert_fp32 = 4 * (2 * d * f + f * d)    # w_gate + w_up + w_down
-    assert eng.stats["moe_stack_bytes"] == total_loads * per_expert_fp32
+    # w_gate + w_up + w_down at the served dtype
+    per_expert = jnp.dtype(cfg.dtype).itemsize * (2 * d * f + f * d)
+    assert eng.stats["moe_stack_bytes"] == total_loads * per_expert
     n_moe_units = sum(1 for u in eng.units if u.moe)
     n_combines = (eng.stats["prefills"]
                   + eng.stats["decode_steps"]) * n_moe_units
     assert eng.stats["moe_stack_bytes"] \
-        < n_combines * m.num_experts * per_expert_fp32
+        < n_combines * m.num_experts * per_expert
     eng.shutdown()
 
 
@@ -662,7 +672,7 @@ def test_pp_two_stage_decode_parity(request, quant, kv_mode, depth):
     else:
         ref = ServingEngine(cfg, b_max=2, max_len=64)
     if quant == "int4":
-        ref.params = quant_roundtrip_params(cfg, ref.params)
+        ref.params = quant_roundtrip_params(cfg, _f32_draw(ref))
     want = _serve(ref, _prompts(cfg))
 
     kw = dict(depth=depth)

@@ -8,6 +8,7 @@ imported: only one process may load the TPU runtime, and a test that
 described it at import would make pytest-xdist workers collect different
 tests.  The persistent compilation cache is off around these compiles (an
 entry written for a described chip cannot be read back without one)."""
+import dataclasses
 import os
 
 import jax
@@ -63,10 +64,11 @@ def _compile(fn, *args, static_argnums=()):
 
 
 def _layer_args(cfg, shape_of):
-    """Shapes of one streamed unit: its weights and its decode cache."""
+    """Shapes of one streamed unit: its weights, at the dtypes the engine
+    serves them at, and its decode cache."""
     params = jax.eval_shape(
         lambda: T.init_params(cfg, jax.random.PRNGKey(0), jnp.float32))
-    w = {n: shape_of(l.shape[1:], l.dtype)
+    w = {n: shape_of(l.shape[1:], T.served_dtype(cfg, n))
          for n, l in params["pat"][0].items()}
     struct, kinds = T.cache_struct(cfg, B, MAX_LEN)
     cache = {n: shape_of(s.shape[1:], s.dtype)
@@ -80,11 +82,18 @@ def _angles(cfg, positions, shape_of):
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill", "embed", "head",
-                                     "kv_int4_dequant"])
+                                     "kv_int4_dequant", "decode_bf16",
+                                     "prefill_bf16"])
 def test_served_program_compiles(shape_of, program):
     """The programs ``OffloadedServingEngine`` jits per decode step, at
-    tinyllama-1.1b widths with f32 weights, b=8 and a 2048-row cache."""
+    tinyllama-1.1b widths with f32 unit weights (``_bf16``: the bf16 unit
+    weights of a configuration that states bf16), b=8 and a 2048-row
+    cache.  A bf16 program reads its weights as they are: the compiled
+    program holds no f32 array of a weight's shape."""
+    program, bf16, _ = program.partition("_bf16")
     cfg = get_config(ARCH)
+    if not bf16:
+        cfg = dataclasses.replace(cfg, dtype="float32")
     dist = Dist.local()
     params, w, cache, kinds = _layer_args(cfg, shape_of)
     decode_fn, prefill_fn, _ = unit_programs(
@@ -116,6 +125,10 @@ def test_served_program_compiles(shape_of, program):
             shape_of((B, 256, F // 2), jnp.uint8),
             shape_of((B, 256, F // g)), g, full, jnp.float32).compile()
     assert c.memory_analysis() is not None
+    if bf16:
+        hlo = c.as_text()
+        assert all(f"f32[{','.join(map(str, a.shape))}]" not in hlo
+                   for a in w.values() if a.ndim == 2)
 
 
 @pytest.mark.parametrize("kernel", ["int4_matmul", "flash_attention",
