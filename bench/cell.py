@@ -24,6 +24,17 @@ window and the engine's own spans and counters.  Each metric is a reader
 ``bench/metrics/<name>.py`` with ``read(run) -> float | None``, found by
 the names in ``BENCHMARK.json`` (``reader_path``).
 
+A configuration file is the ``ModelConfig`` it describes (``model_config``):
+every key that names a ``ModelConfig`` field is passed as it stands; a
+dict under ``moe``, ``mla`` or ``ssm`` becomes that sub-config, field by
+field; ``pattern`` and ``remainder`` are lists of ``[mixer, ffn]``, and a
+one-kind stack may give ``mixer`` and ``ffn`` instead.  The harness's own
+keys are ``HARNESS_KEYS``; ``reference`` names the file of the plain
+reference (``bench/reference.py`` states what such a file provides).  Any
+other key is an error.  A limits file (``bench/limits/<cell>.json``) bounds
+numbers over the served tokens' gaps (``gap_number``): ``served_gap``,
+``share_over_<g>``, or both.
+
 Without a TPU, or with fewer chips than the cell asks for, the run
 prints no result and exits with code 2.  The JAX compilation cache lives
 in ``bench/.jax_cache`` inside the checkout, so only a checkout's first
@@ -74,11 +85,15 @@ def load_json(rel: str) -> dict:
 
 
 def load_module(path: Path):
-    """Import a file of the benchmark by path (names may hold dots)."""
+    """Import a file of the benchmark by path (names may hold dots); a
+    file already imported from that path is not run again."""
     if not path.is_file():
         raise BenchError(f"{path.relative_to(ROOT)} is missing")
-    spec = importlib.util.spec_from_file_location(
-        "bench_" + path.stem.replace(".", "_").replace("-", "_"), path)
+    name = "bench_" + path.stem.replace(".", "_").replace("-", "_")
+    mod = sys.modules.get(name)
+    if mod is not None and Path(mod.__file__) == path:
+        return mod
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     sys.modules[spec.name] = mod
     spec.loader.exec_module(mod)
@@ -111,6 +126,8 @@ def find_cell(name: str, bench: Optional[dict] = None) -> Cell:
     config = load_json(configs[w["config"]]["file"])
     mix = load_json(f"bench/traffic/{w['traffic']}.json")
     limits = load_json(f"bench/limits/{name}.json")
+    for k in limits:
+        gap_number(k, [0.0])             # a name no run can compute fails here
     return Cell(name, int(w["chips"]), config, mix,
                 [m for m in bench["end_to_end"] if _reports(m, name)],
                 [m for m in bench["per_layer"] if _reports(m, name)],
@@ -174,30 +191,78 @@ def bytes_in_use(devs) -> int:
 # engine
 # ---------------------------------------------------------------------------
 
+# Keys of a configuration file that the harness reads itself; every other
+# key names a ``ModelConfig`` field.
+HARNESS_KEYS = ("source", "reference", "precision", "placement",
+                "device_budget", "published", "reduced", "assumed",
+                "deployment", "mixer", "ffn")
+
+
+def _fields(cls, d: dict, where: str) -> dict:
+    """``d`` as keyword arguments of dataclass ``cls``: a key that names no
+    field is an error; a value is cast to its field's default's float or
+    bool, and a list becomes a tuple, as a frozen config holds them."""
+    import dataclasses
+    fields = {f.name: f for f in dataclasses.fields(cls)}
+    out = {}
+    for k, v in d.items():
+        if k not in fields:
+            raise BenchError(f"configuration key {where}{k!r} is neither a "
+                             f"{cls.__name__} field nor one the harness "
+                             f"reads ({', '.join(HARNESS_KEYS)})")
+        default = fields[k].default
+        if isinstance(default, bool) and v is not None:
+            v = bool(v)
+        elif isinstance(default, float) and v is not None:
+            v = float(v)
+        elif isinstance(v, list):
+            v = tuple(v)
+        out[k] = v
+    return out
+
+
 def model_config(c: dict):
-    """The served ``ModelConfig`` from a configuration file."""
-    from repro.configs.base import LayerSpec, ModelConfig, MoEConfig
-    moe = c.get("moe")
-    return ModelConfig(
-        name=c["name"], family="moe" if moe else "dense",
-        num_layers=c["num_layers"], d_model=c["d_model"],
-        num_heads=c["num_heads"], num_kv_heads=c["num_kv_heads"],
-        head_dim=c["head_dim"], d_ff=c["d_ff"], vocab_size=c["vocab_size"],
-        max_seq_len=c["max_seq_len"],
-        pattern=(LayerSpec(c["mixer"], c["ffn"]),),
-        rope_theta=float(c["rope_theta"]), norm_eps=float(c["norm_eps"]),
-        tie_embeddings=bool(c["tie_embeddings"]),
-        moe=MoEConfig(**moe) if moe else None)
+    """The served ``ModelConfig`` that a configuration file describes (the
+    module docstring gives the format)."""
+    from repro.configs.base import (LayerSpec, MLAConfig, ModelConfig,
+                                    MoEConfig, SSMConfig)
+    kw = _fields(ModelConfig, {k: v for k, v in c.items()
+                               if k not in HARNESS_KEYS}, "")
+    for k, cls in (("moe", MoEConfig), ("mla", MLAConfig),
+                   ("ssm", SSMConfig)):
+        if kw.get(k) is not None:
+            kw[k] = cls(**_fields(cls, kw[k], f"{k}."))
+    for k in ("pattern", "remainder"):
+        if k in kw:
+            kw[k] = tuple(LayerSpec(*p) for p in kw[k])
+    one_kind = [k for k in ("mixer", "ffn") if k in c]
+    if len(one_kind) != (0 if "pattern" in kw else 2):
+        raise BenchError(f"configuration {c.get('name')!r} must give either "
+                         f"pattern or mixer and ffn")
+    if "pattern" not in kw:
+        kw["pattern"] = (LayerSpec(c["mixer"], c["ffn"]),)
+    kw.setdefault("family", "moe" if kw.get("moe") else "dense")
+    return ModelConfig(**kw)
+
+
+def reference_of(c: dict):
+    """The plain reference module that the configuration's ``reference``
+    key names, a path from the checkout's root."""
+    if "reference" not in c:
+        raise BenchError(f"configuration {c.get('name')!r} names no "
+                         f"reference")
+    return load_module(ROOT / c["reference"])
 
 
 def resident_bytes(c: dict) -> int:
     """Device-resident tensors at the stated bf16: the embedding, an
-    untied head, the final norm and the MoE routers."""
-    from reference import padded_vocab
+    untied head, the final norm and the router of each MoE layer."""
+    costs = load_module(BENCH / "costs.py")
     d = c["d_model"]
-    n = padded_vocab(c) * d * (1 if c["tie_embeddings"] else 2) + d
+    n = reference_of(c).padded_vocab(c) * d * (1 if c["tie_embeddings"] else 2) + d
     if c.get("moe"):
-        n += c["num_layers"] * d * c["moe"]["num_experts"]
+        n += (sum(1 for _, ffn in costs.layers(c) if ffn == "moe")
+              * d * c["moe"]["num_experts"])
     return 2 * n
 
 
@@ -414,7 +479,8 @@ def served_gaps(config: dict, seed: int, picked, length: int,
     the reference compiles once per cell.  Returns (program gaps, control
     gaps), lists of arrays."""
     import numpy as np
-    import reference
+    reference = reference_of(config)
+    log(f"check: reference {config['reference']}")
     w = reference.init_weights(config, seed)
     prog, ctrl = [], []
     for r in picked:
@@ -558,19 +624,41 @@ def serve(cell: Cell, seed: int, seconds: float, trace: bool, devs,
     return out
 
 
-# The numbers a cell's limits file may hold, each over the served tokens
-# compared: the widest gap.
+# The numbers a cell's limits file may hold, each over the gaps of the
+# served tokens compared (``gap_number``): the widest gap, and
+# ``share_over_<g>``, the percentage of served tokens whose gap exceeds
+# ``<g>``.  Every run reads the widest gap and the shares at CONTROL_SHARES
+# besides the numbers its limits file bounds.
 GAP_NUMBERS = {
     "served_gap": lambda g: float(g.max()),
 }
+SHARE_OVER = "share_over_"
+CONTROL_SHARES = tuple(f"{SHARE_OVER}{g}" for g in ("0.01", "0.05", "0.1"))
+
+
+def gap_number(name: str, gaps) -> float:
+    """Check number ``name`` over the served tokens' gaps."""
+    import numpy as np
+    g = np.asarray(gaps, np.float64)
+    if name in GAP_NUMBERS:
+        return GAP_NUMBERS[name](g)
+    if name.startswith(SHARE_OVER):
+        try:
+            over = float(name[len(SHARE_OVER):])
+        except ValueError:
+            over = float("nan")
+        if np.isfinite(over) and over >= 0:
+            return 100.0 * float(np.mean(g > over))
+    raise BenchError(f"no check number {name!r}: a limits file may bound "
+                     f"{sorted(GAP_NUMBERS)} and {SHARE_OVER}<gap>")
 
 
 def check(cell: Cell, seed: int, reqs, fp8: bool = False):
     """(check, correct, program readings, control readings or None): each
-    number of the cell's limits file (``GAP_NUMBERS``) over a sample of
-    the served requests, beside its limit; every number of
-    ``GAP_NUMBERS`` for the program and, with ``fp8``, for the float8
-    control."""
+    number of the cell's limits file over a sample of the served
+    requests, beside its limit; ``GAP_NUMBERS``, ``CONTROL_SHARES`` and the
+    limits file's numbers for the program and, with ``fp8``, for the
+    float8 control."""
     import numpy as np
     t = time.perf_counter()
     picked = sample(reqs, cell.mix, seed)
@@ -582,13 +670,14 @@ def check(cell: Cell, seed: int, reqs, fp8: bool = False):
     if not g.size:
         return ({k: {"value": None, "limit": v}
                  for k, v in cell.limits.items()}, False, None, None)
-    prog = {k: f(g) for k, f in GAP_NUMBERS.items()}
+    names = dict.fromkeys([*GAP_NUMBERS, *CONTROL_SHARES, *cell.limits])
+    prog = {k: gap_number(k, g) for k in names}
     chk = {k: {"value": prog[k], "limit": v} for k, v in cell.limits.items()}
     for k in prog:
         if k not in chk:
             log(f"check: {k}={prog[k]} (not compared in this cell)")
     correct = all(c["value"] <= c["limit"] for c in chk.values())
-    ctl = ({k: f(np.concatenate(ctrl)) for k, f in GAP_NUMBERS.items()}
+    ctl = ({k: gap_number(k, np.concatenate(ctrl)) for k in names}
            if ctrl else None)
     return chk, correct, prog, ctl
 

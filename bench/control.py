@@ -8,13 +8,15 @@ For each seed, in one process: the cell's own run (set-up, warm-up and a
 window of ``--seconds`` at the cell's load, through ``bench/cell.py``),
 then over the same sample of served requests
 
-- the program's readings: the numbers a run compares with the cell's
-  limits (``bench/cell.py::GAP_NUMBERS``), over the gaps by which each
-  served token's reference logit lies below the reference's best, and
+- the program's readings: the numbers a run may compare with the cell's
+  limits (``bench/cell.py::gap_number``: ``served_gap``, and
+  ``share_over_<g>`` at 0.01, 0.05, 0.1 and any ``<g>`` the limits file
+  names), over the gaps by which each served token's reference logit
+  lies below the reference's best, and
 - the control's readings: the same numbers over the gaps of the token
-  that the reference computed with float8 weights
-  (``bench/reference.py``, one step below the stated bf16) puts first at
-  each position.
+  that the reference computed with float8 weights (the reference module
+  that the configuration's ``reference`` key names, one step below the
+  stated bf16) puts first at each position.
 
 One line per seed, then a JSON summary per number: the largest program
 reading (the lower end of its limit) and the smallest control reading (the

@@ -1,6 +1,24 @@
 """Plain float32 reference of the served decoder stacks, and the recipe of
 their random weights.
 
+A configuration file names the reference that judges it (its
+``reference`` key, a path from the checkout's root), and
+``bench/cell.py`` loads that file by path.  A reference file provides,
+for the configuration dict ``m``:
+
+- ``init_weights(m, seed)``: the weights, drawn from ``seed`` by the
+  same recipe as the served engine, and nothing taken from the program;
+- ``hidden(m, w, toks, fp8=False)``: the last layer's output (n, s, d)
+  for token rows ``toks`` (n, s), causal; ``fp8=True`` is the control,
+  the same forward one precision step below the stated one;
+- ``judge(m, w, x, tokens, fp8=False)``: per position of ``x``, the best
+  logit, the logit of ``tokens`` (n, s) and the token that comes first,
+  as numpy arrays;
+- ``padded_vocab(m)``: the rows of the embedding and the head as the
+  served engine holds them.
+
+This file is the reference of the dense and Mixtral-style stacks below.
+
 Written from the published descriptions, independent of ``src/``:
 
 - Llama-style decoder layer (Granite Code, arXiv:2405.04324; Mixtral,

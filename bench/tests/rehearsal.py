@@ -2,9 +2,11 @@
 
 ``checkout(tmp)`` copies ``bench/`` beside a ``BENCHMARK.json`` that adds
 one cell built only from new files: a tiny configuration
-(``bench/configs/tiny.json``), a tiny mix (``bench/traffic/tiny-closed.json``)
-and its limit (``bench/limits/tiny.closed.json``), and lists the new cell
-under every metric that the full-size cell reports.  No file that the
+(``bench/configs/tiny.json``, dense or with routed experts, judged by
+``bench/reference.py`` or by a copy of it under a name of its own), a tiny
+mix (``bench/traffic/tiny-closed.json``) and its limits
+(``bench/limits/tiny.closed.json``), and lists the new cell under every
+metric that the full-size cell reports.  No file that the
 benchmark already has is edited.  ``load_cell`` imports that
 checkout's ``cell.py`` with the look for a chip replaced by JAX's own
 devices, so the rest of a run drives the CPU.
@@ -18,6 +20,7 @@ import json
 import shutil
 import sys
 from pathlib import Path
+from typing import Optional
 
 REPO = Path(__file__).resolve().parents[2]
 FULL_CELL = "granite-8b-l12.offline"
@@ -56,17 +59,24 @@ def tiny_config(moe: bool) -> dict:
     return c
 
 
-def checkout(tmp: Path, limit: float = 0.05) -> str:
+def checkout(tmp: Path, limit: float = 0.05, moe: bool = False,
+             reference: Optional[str] = None,
+             limits: Optional[dict] = None) -> str:
     """Write the scratch checkout under ``tmp``; returns the new cell's
-    name."""
+    name.  ``reference``: a new path, from the checkout's root, to which
+    ``bench/reference.py`` is copied for the tiny configuration to name;
+    ``limits``: the limits file, ``{"served_gap": limit}`` if not given."""
     shutil.copytree(REPO / "bench", tmp / "bench",
                     ignore=shutil.ignore_patterns(".jax_cache", "__pycache__"))
-    (tmp / "bench" / "configs" / "tiny.json").write_text(
-        json.dumps(tiny_config(False)))
+    config = tiny_config(moe)
+    if reference:
+        shutil.copyfile(REPO / "bench" / "reference.py", tmp / reference)
+        config["reference"] = reference
+    (tmp / "bench" / "configs" / "tiny.json").write_text(json.dumps(config))
     (tmp / "bench" / "traffic" / "tiny-closed.json").write_text(json.dumps(MIX))
     name = "tiny.closed"
     (tmp / "bench" / "limits" / f"{name}.json").write_text(
-        json.dumps({"served_gap": limit}))
+        json.dumps(limits if limits is not None else {"served_gap": limit}))
     bench = json.loads((REPO / "BENCHMARK.json").read_text())
     bench["configs"].append({"name": "tiny", "source": "tiny test size",
                              "file": "bench/configs/tiny.json",

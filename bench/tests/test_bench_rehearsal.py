@@ -5,6 +5,7 @@ new files are found with no existing file edited; a broken timed path
 makes ``correct`` false; ``BENCHMARK.json`` keeps to its contract."""
 from __future__ import annotations
 
+import importlib.util
 import json
 import re
 import sys
@@ -27,6 +28,13 @@ def run(cell_mod, name, capsys, trace=0, seconds=2.0):
     captured = capsys.readouterr()
     lines = captured.out.strip().splitlines()
     return rc, (json.loads(lines[-1]) if lines else None), captured.err
+
+
+def bench_files():
+    """{path under bench/: bytes} of every file the benchmark has."""
+    return {p.relative_to(REPO / "bench"): p.read_bytes()
+            for p in (REPO / "bench").rglob("*") if p.is_file()
+            and "__pycache__" not in p.parts and ".jax_cache" not in p.parts}
 
 
 def test_cell_runs_end_to_end(tmp_path, capsys):
@@ -79,15 +87,66 @@ def test_new_files_are_found(tmp_path, capsys):
                                 "better": "higher", "bound": 0.25,
                                 "source": "host_clock", "workloads": [name]})
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
-    before = {p.relative_to(REPO / "bench"): p.read_bytes()
-              for p in (REPO / "bench").rglob("*") if p.is_file()
-              and "__pycache__" not in p.parts and ".jax_cache" not in p.parts}
+    before = bench_files()
     cell = rehearsal.load_cell(tmp_path)
     rc, res, err = run(cell, name, capsys)
     assert rc == 0, err[-2000:]
     assert res["metrics"]["tokens_in_window"]["value"] > 0
     for rel, data in before.items():
         assert (tmp_path / "bench" / rel).read_bytes() == data, rel
+
+
+def test_a_reference_the_configuration_names_judges_the_run(tmp_path, capsys):
+    """The tiny MoE cell's configuration names a new reference file (a copy
+    of ``bench/reference.py`` under another name) and its limits file
+    bounds ``share_over_0.05``: the run loads that file, reads the number
+    and reports ``correct``, with no file of the benchmark edited."""
+    name = rehearsal.checkout(tmp_path, moe=True,
+                              reference="bench/tiny_moe_reference.py",
+                              limits={"share_over_0.05": 5.0})
+    before = bench_files()
+    cell = rehearsal.load_cell(tmp_path)
+    rc, res, err = run(cell, name, capsys)
+    assert rc == 0, err[-2000:]
+    assert "check: reference bench/tiny_moe_reference.py" in err
+    ref = sys.modules["bench_tiny_moe_reference"]
+    assert (Path(ref.__file__).resolve()
+            == (tmp_path / "bench" / "tiny_moe_reference.py").resolve())
+    assert list(res["check"]) == ["share_over_0.05"]
+    assert res["check"]["share_over_0.05"]["value"] <= 5.0
+    assert err.strip().splitlines()[-1].startswith("check share_over_0.05=")
+    assert res["correct"] is True
+    for rel, data in before.items():
+        assert (tmp_path / "bench" / rel).read_bytes() == data, rel
+
+
+def test_control_reads_the_shares_for_program_and_control(tmp_path, capsys,
+                                                         monkeypatch):
+    """``bench/control.py`` on the tiny MoE cell, with the scratch
+    checkout's ``cell.py`` (its look for a chip replaced): for each seed
+    the program's and the float8 control's ``served_gap`` and
+    ``share_over_`` at 0.01, 0.05 and 0.1, then the lower and upper
+    readings of each."""
+    name = rehearsal.checkout(tmp_path, moe=True)
+    monkeypatch.setitem(sys.modules, "cell", rehearsal.load_cell(tmp_path))
+    spec = importlib.util.spec_from_file_location(
+        "bench_control_test", tmp_path / "bench" / "control.py")
+    control = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(control)
+    rc = control.main(["--workload", name, "--seconds", "1",
+                       "--seeds", str(SEED), str(SEED + 1)])
+    out = capsys.readouterr().out.strip().splitlines()
+    assert rc == 0
+    summary = json.loads(out[-1])
+    numbers = {"served_gap", "share_over_0.01", "share_over_0.05",
+               "share_over_0.1"}
+    assert set(summary["readings"]) == numbers
+    for row in summary["rows"]:
+        assert set(row["program"]) == set(row["control"]) == numbers
+    got = summary["readings"]["share_over_0.05"]
+    # bf16 weights flip near-tie routing on a few tokens; float8 on many
+    assert got["upper"] >= 3 * got["lower"]
+    assert sum(line.startswith("control tiny.closed seed") for line in out) == 2
 
 
 def _alter_tokens(cell, fault):
@@ -123,6 +182,19 @@ def test_broken_timed_path_is_not_correct(tmp_path, capsys, fault):
     assert rc == 0, err[-2000:]
     assert res["correct"] is False
     assert res["check"]["served_gap"]["value"] > res["check"]["served_gap"]["limit"]
+
+
+@pytest.mark.parametrize("fault", ["altered", "unchanged"])
+def test_broken_timed_path_fails_the_share_check(tmp_path, capsys, fault):
+    """The tiny MoE cell judged by ``share_over_0.05`` alone."""
+    name = rehearsal.checkout(tmp_path, moe=True,
+                              limits={"share_over_0.05": 5.0})
+    cell = rehearsal.load_cell(tmp_path)
+    _alter_tokens(cell, fault)
+    rc, res, err = run(cell, name, capsys)
+    assert rc == 0, err[-2000:]
+    assert res["correct"] is False
+    assert res["check"]["share_over_0.05"]["value"] > 5.0
 
 
 def test_no_accelerator_prints_no_result(tmp_path, capsys):

@@ -82,7 +82,7 @@ def test_decode_roofline_bound_at_full_width():
     of bf16 weights at 819 GB/s is ~0.53 ms; its ~7 GFLOP take ~36 us)."""
     import json
     c = json.loads((HERE.parent / "configs" / "granite-8b-l12.json").read_text())
-    f, b = costs.decode_unit(c, 16, 16 * 600)
+    f, b = costs.decode_unit(c, [600] * 16, ("attn", "dense"))
     pk = peaks.lookup("TPU v5 lite")
     assert b / pk["hbm_bytes_per_s"] > f / pk["bf16_flops"]
     assert 0.5e-3 < b / pk["hbm_bytes_per_s"] < 0.7e-3
